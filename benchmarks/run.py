@@ -79,6 +79,8 @@ def select(modules: Dict[str, object],
 
 
 def main(argv: Optional[List[str]] = None) -> int:
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache(Path(__file__).resolve().parent.parent)
     ap = argparse.ArgumentParser(
         prog="benchmarks.run", description=__doc__.splitlines()[0])
     ap.add_argument("--only", action="append", metavar="NAME",

@@ -3,7 +3,7 @@
 PR 5's convention: JAX runs in float32 by default, and the exact
 eviction kernels that need double precision (stack-distance ties,
 byte-exact eviction accounting) opt in with the *scoped*
-``jax.experimental.enable_x64()`` context manager — never the global
+``jax.enable_x64()`` context manager — never the global
 ``jax.config.update("jax_enable_x64", ...)`` switch, which would flip
 precision (and recompile) for every other kernel in the process.  This
 rule flags, in ``kernels/`` modules only:
@@ -33,7 +33,7 @@ def _dotted(node: ast.expr) -> str:
 
 
 def _x64_spans(tree: ast.Module) -> List[Tuple[int, int]]:
-    """Line spans of ``with enable_x64():`` bodies."""
+    """Line spans of ``with jax.enable_x64():`` bodies."""
     spans: List[Tuple[int, int]] = []
     for node in ast.walk(tree):
         if not isinstance(node, (ast.With, ast.AsyncWith)):
@@ -88,7 +88,7 @@ class X64ScopingChecker(Checker):
                         "global jax_enable_x64 config flip — this "
                         "recompiles and changes precision for every "
                         "kernel in the process; use the scoped "
-                        "jax.experimental.enable_x64() context manager"))
+                        "jax.enable_x64() context manager"))
                 elif isinstance(node, ast.Call):
                     for kw in node.keywords:
                         if kw.arg == "dtype" \

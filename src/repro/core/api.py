@@ -1924,11 +1924,14 @@ def _cell_routing(spec: ScenarioSpec, fed: Federation, state: Dict,
         stream.arrays()
     # The distance/replay scans are O(N) per reference (O(N²) per
     # stream); surface the longest stream so a sweep that drifts into
-    # that regime is diagnosable from report.solver.
+    # that regime is diagnosable from report.solver, and the shortest,
+    # which says whether every cache saw a realistic stream.
     if streams_by_cache:
+        lengths = [len(s.req) for s in streams_by_cache.values()]
         telemetry["max_stream_refs"] = max(
-            telemetry.get("max_stream_refs", 0),
-            max(len(s.req) for s in streams_by_cache.values()))
+            telemetry.get("max_stream_refs", 0), max(lengths))
+        telemetry["min_stream_refs"] = min(
+            telemetry.get("min_stream_refs", min(lengths)), min(lengths))
 
     # ---- cell-independent counters and flow constants ----------------------
     cache_failovers = int((nreq[served_mask] * dead_before[served_mask])
@@ -2041,6 +2044,20 @@ def _cell_routing(spec: ScenarioSpec, fed: Federation, state: Dict,
     return routing
 
 
+def _tally(telemetry: Dict, kind: str, stats: Dict) -> None:
+    """Fold one kernel's batch stats into the sweep telemetry as
+    ``<kind>_calls`` / ``_problems`` / ``_padded`` / ``_buckets`` — the
+    jitted calls made, the problems solved, the all-dummy batch filler,
+    and the padded shape of every call (the size that really ran)."""
+    telemetry[f"{kind}_calls"] = (telemetry.get(f"{kind}_calls", 0)
+                                  + stats["solve_calls"])
+    telemetry[f"{kind}_problems"] = (telemetry.get(f"{kind}_problems", 0)
+                                     + stats["problems"])
+    telemetry[f"{kind}_padded"] = (telemetry.get(f"{kind}_padded", 0)
+                                   + stats["padded_problems"])
+    telemetry.setdefault(f"{kind}_buckets", []).extend(stats["buckets"])
+
+
 def _resolve_distances(wanted: Sequence[Tuple[_CacheStream, bytes,
                                               np.ndarray]],
                        telemetry: Dict) -> None:
@@ -2077,10 +2094,7 @@ def _resolve_distances(wanted: Sequence[Tuple[_CacheStream, bytes,
         problems.append((prev, stream.size[sel].astype(np.float64)))
     kstats: Dict = {}
     dists = stack_distances_batch(problems, stats=kstats)
-    telemetry["stack_calls"] = (telemetry.get("stack_calls", 0)
-                                + kstats["solve_calls"])
-    telemetry["stack_variants"] = (telemetry.get("stack_variants", 0)
-                                   + len(pending))
+    _tally(telemetry, "stack", kstats)
     for (stream, sig, _), (sel, fkeys, fseg), dist in zip(
             pending, selections, dists):
         fsizes = stream.size[sel]
@@ -2607,14 +2621,12 @@ def run_sweep(spec: SweepSpec, batched: bool = True,
         from repro.kernels.stack_distance import fifo_sim_batch
         fifo_stats: Dict = {}
         fifo_results = fifo_sim_batch(fifo_problems, stats=fifo_stats)
-        telemetry["fifo_calls"] = fifo_stats["solve_calls"]
-        telemetry["fifo_problems"] = fifo_stats["problems"]
+        _tally(telemetry, "fifo", fifo_stats)
     if sim_problems:
         from repro.kernels.stack_distance import cache_sim_batch
         sim_stats: Dict = {}
         sim_results = cache_sim_batch(sim_problems, stats=sim_stats)
-        telemetry["cache_sim_calls"] = sim_stats["solve_calls"]
-        telemetry["cache_sim_problems"] = sim_stats["problems"]
+        _tally(telemetry, "cache_sim", sim_stats)
 
     # round 2: parent-tier caches see their direct references merged
     # with the fills the children's misses induced, so their problems
@@ -2642,21 +2654,13 @@ def run_sweep(spec: SweepSpec, batched: bool = True,
         l2_fifo_stats: Dict = {}
         l2_fifo_results = fifo_sim_batch(l2_fifo_problems,
                                          stats=l2_fifo_stats)
-        telemetry["fifo_calls"] = (telemetry.get("fifo_calls", 0)
-                                   + l2_fifo_stats["solve_calls"])
-        telemetry["fifo_problems"] = (telemetry.get("fifo_problems", 0)
-                                      + l2_fifo_stats["problems"])
+        _tally(telemetry, "fifo", l2_fifo_stats)
     if l2_sim_problems:
         from repro.kernels.stack_distance import cache_sim_batch
         l2_sim_stats: Dict = {}
         l2_sim_results = cache_sim_batch(l2_sim_problems,
                                          stats=l2_sim_stats)
-        telemetry["cache_sim_calls"] = (
-            telemetry.get("cache_sim_calls", 0)
-            + l2_sim_stats["solve_calls"])
-        telemetry["cache_sim_problems"] = (
-            telemetry.get("cache_sim_problems", 0)
-            + l2_sim_stats["problems"])
+        _tally(telemetry, "cache_sim", l2_sim_stats)
     if l2_sim_problems or l2_fifo_problems or l2_dist_wanted:
         telemetry["tier_rounds"] = 2
 

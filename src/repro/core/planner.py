@@ -41,7 +41,6 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.kernels.cache_model import (CacheModel, StackedModels,
                                        fleet_hit_rate, fleet_origin_egress,
@@ -295,7 +294,7 @@ def plan_capacity(spec: PlannerSpec, federation=None) -> PlanReport:
         for cache in groups[g]:
             gidx[pos[cache]] = gi
         gsize[gi] = len(groups[g])
-    with enable_x64():
+    with jax.enable_x64():
         caps, uni, pred_hit, pred_egress, gnorm = (
             np.asarray(x, np.float64) for x in _solve(
                 stacked, gidx, gsize, spec))

@@ -41,7 +41,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 DEFAULT_BUCKETS = 64
 # Smoothing temperature in log-capacity space: ~5% capacity error per
@@ -332,7 +331,7 @@ def fit_lognormal_mixture(hist: ReuseHistogram, components: int = 3,
     qs = (np.arange(components) + 0.5) / components
     mu0 = _quantiles(m, w, qs)
     spread = max(float(m.max() - m.min()), 0.1)
-    with enable_x64():
+    with jax.enable_x64():
         params0 = (jnp.zeros(components, jnp.float64),
                    jnp.asarray(mu0, jnp.float64),
                    jnp.full(components,

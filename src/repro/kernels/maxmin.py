@@ -161,7 +161,9 @@ def maxmin_rates_sparse(link_caps: Sequence[float],
     caps, ids, fcaps = pad_problem(link_caps, flow_links, flow_caps,
                                    Fp, Lp, width)
     rates = _solve(jnp.asarray(caps), jnp.asarray(ids), jnp.asarray(fcaps))
-    out = np.array(rates[:F])
+    # slice on the host: slicing the device array compiles one program
+    # per distinct flow count
+    out = np.array(rates)[:F]
     # Flows crossing no capacity-bearing link (loopback transfers) look
     # identical to padding inside ``_solve`` — all-dummy rows retired at
     # rate 0 — but are real flows bound only by their own TCP cap, which

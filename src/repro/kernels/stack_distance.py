@@ -34,9 +34,9 @@ was *chosen* as a victim), mirroring ``CacheServer.clear``.
 
 Byte counters must be exact — a one-byte error flips an eviction
 decision and breaks the sweep's cell-exact parity guarantee — so both
-kernels run in float64 under a scoped :func:`jax.experimental.
-enable_x64` (integers up to 2**53 are exact, far above any capacity the
-federation models).  ``tests/test_stack_distance.py`` holds both
+kernels run in float64 under a scoped ``with jax.enable_x64():``
+(integers up to 2**53 are exact, far above any capacity the federation
+models).  ``tests/test_stack_distance.py`` holds both
 kernels byte-equal to a scalar :class:`~repro.core.cache.CacheServer`
 oracle replay.
 """
@@ -47,7 +47,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from .maxmin import _next_pow2
 
@@ -248,7 +247,7 @@ def stack_distances_batch(problems: Sequence[DistanceProblem],
     for i, (prev, _) in enumerate(problems):
         by_bucket.setdefault(_next_pow2(max(len(prev), 1), floor=_FLOOR_N),
                              []).append(i)
-    with enable_x64():
+    with jax.enable_x64():
         for Np, idxs in sorted(by_bucket.items()):
             B = _next_pow2(len(idxs), floor=1)
             prevs = np.full((B, Np), -1, np.int64)
@@ -294,7 +293,7 @@ def fifo_sim_batch(problems: Sequence[FifoProblem],
         bucket = (_next_pow2(max(len(keys), 1), floor=_FLOOR_N),
                   _next_pow2(max(n_keys, 1), floor=_FLOOR_K))
         by_bucket.setdefault(bucket, []).append(i)
-    with enable_x64():
+    with jax.enable_x64():
         for (Np, Kp), idxs in sorted(by_bucket.items()):
             B = _next_pow2(len(idxs), floor=1)
             keys = np.zeros((B, Np), np.int32)
@@ -339,7 +338,7 @@ def cache_sim_batch(problems: Sequence[SimProblem],
         bucket = (_next_pow2(max(len(keys), 1), floor=_FLOOR_N),
                   _next_pow2(max(len(key_sizes), 1), floor=_FLOOR_K))
         by_bucket.setdefault(bucket, []).append(i)
-    with enable_x64():
+    with jax.enable_x64():
         for (Np, Kp), idxs in sorted(by_bucket.items()):
             B = _next_pow2(len(idxs), floor=1)
             keys = np.zeros((B, Np), np.int32)

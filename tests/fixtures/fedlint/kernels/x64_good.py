@@ -1,11 +1,11 @@
 """Good fixture: x64-scoping — JAX float64 only under enable_x64."""
+import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 
 def exact_distances(refs):
-    with enable_x64():
+    with jax.enable_x64():
         xs = jnp.asarray(refs, jnp.float64)
         return jnp.cumsum(xs)
 

@@ -10,7 +10,6 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core import (FederationSpec, PlannerSpec, ScenarioSpec,
                         SweepAggregator, SweepSpec, WorkloadSpec,
@@ -183,7 +182,7 @@ class TestGradients:
     def test_grad_flows_through_fleet_hit_rate(self, fit_report):
         models = fit_report.fitted_models()
         stacked = stack_models(models)
-        with enable_x64():
+        with jax.enable_x64():
             def fleet(logc):
                 return fleet_hit_rate(stacked, jnp.exp(logc))
 
@@ -197,7 +196,7 @@ class TestGradients:
         models = fit_report.fitted_models()
         stacked = stack_models(models)
         caps = {n: 3e9 for n in models}
-        with enable_x64():
+        with jax.enable_x64():
             fleet = float(fleet_hit_rate(
                 stacked, jnp.asarray([caps[n] for n in stacked.names],
                                      jnp.float64)))

@@ -317,6 +317,19 @@ class TestEvictionParity:
             assert (t.summary["origin_egress_bytes"]
                     > h.summary["origin_egress_bytes"])
 
+    def test_kernel_telemetry_accounts_every_call(self, reports):
+        """Each hit/miss kernel reports one padded bucket per jitted
+        call, and its batches hold exactly the problems plus the
+        all-dummy filler."""
+        batched, _ = reports
+        s = batched.solver
+        for kind in ("stack", "fifo"):
+            buckets = s[f"{kind}_buckets"]
+            assert s[f"{kind}_calls"] == len(buckets) >= 1, kind
+            assert sum(b[0] for b in buckets) \
+                == s[f"{kind}_problems"] + s[f"{kind}_padded"], kind
+        assert 0 < s["min_stream_refs"] <= s["max_stream_refs"]
+
     def test_admission_rejects_are_counted(self, reports):
         batched, _ = reports
         filtered = [c for c in batched.cells
