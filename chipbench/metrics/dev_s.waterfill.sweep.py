@@ -1,0 +1,12 @@
+"""Device seconds per query of the ``solve_waterfill`` program (its XLA
+module's events in the traced window, over the queries traced)."""
+from bench.trace import module_seconds
+
+MODULES = ("jit_solve_waterfill",)
+
+
+def read(run):
+    if run.trace is None or not run.trace["queries"]:
+        return None
+    secs = module_seconds(run.trace, MODULES)
+    return None if secs is None else secs / run.trace["queries"]

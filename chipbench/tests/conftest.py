@@ -1,0 +1,7 @@
+"""Make the benchmark's modules (``run``, ``bench``) importable."""
+import pathlib
+import sys
+
+HERE = pathlib.Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(HERE))
+sys.path.insert(0, str(HERE.parent / "src"))
