@@ -3,8 +3,9 @@
 ``jax.jit`` traces a function once and replays the compiled
 computation; host side effects inside the traced body execute at trace
 time only (or never again), so a ``time.time()``, an unseeded
-``random``/``np.random`` draw, ``print``, file I/O, or ``global``/
-``nonlocal`` mutation there is almost always a bug — the value is
+``random``/``np.random`` draw, ``print``, file I/O, a profiler span
+(``jax.profiler.TraceAnnotation``), or ``global``/``nonlocal`` mutation
+there is almost always a bug — the value is
 frozen into the compiled graph and every later call silently reuses
 it.  This rule finds every function that flows into ``jax.jit`` /
 ``jax.vmap`` / ``jax.pmap`` / ``jax.lax.scan`` (decorators, including
@@ -31,6 +32,11 @@ _EFFECT_CALLS = {
     "time.sleep": "blocks the host at trace time only",
     "datetime.now": "reads the host clock at trace time",
     "os.urandom": "draws host entropy at trace time",
+    # also matches jax.profiler.TraceAnnotation and other dotted spellings
+    "TraceAnnotation": "opens a profiler span at trace time only, never "
+                       "when the program runs",
+    "StepTraceAnnotation": "opens a profiler span at trace time only, "
+                           "never when the program runs",
 }
 # bare names that are host effects
 _EFFECT_NAMES = {

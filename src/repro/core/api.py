@@ -46,7 +46,9 @@ from typing import (Dict, Generator, List, Optional, Protocol, Sequence,
                     Set, Tuple, Union, runtime_checkable)
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
+from .. import spans
 from .client import StashClient
 from .controlplane import ControlPlane, ControlPlaneSpec
 from .federation import Federation, FederationSpec, SiteSpec
@@ -1457,7 +1459,8 @@ class _SharedFederations:
         for known, fed, routes in self._entries:
             if known == spec:
                 return fed, routes
-        fed = spec.build()
+        with TraceAnnotation(spans.ROUTE):
+            fed = spec.build()
         state: Dict = {"routes": {}, "clients": {}, "cells": []}
         self._entries.append((spec, fed, state))
         return fed, state
@@ -1589,459 +1592,464 @@ def _cell_routing(spec: ScenarioSpec, fed: Federation, state: Dict,
     (unresolvable namespace — the serial path raises ``KeyError``),
     in which case the caller falls back to the serial executor.
     """
-    reqs = spec.requests(fed)
-    n = len(reqs)
-    default_site = next((s.name for s in fed.sites if s.workers > 0),
-                        fed.sites[0].name)
+    with TraceAnnotation(spans.ROUTE_STREAMS):
+        reqs = spec.requests(fed)
+        n = len(reqs)
+        default_site = next((s.name for s in fed.sites if s.workers > 0),
+                            fed.sites[0].name)
 
-    # ---- request arrays (original order) -----------------------------------
-    path_ids: Dict[str, int] = {}
-    sizes: List[int] = []
-    pid = np.empty(n, np.int64)
-    at = np.empty(n, np.float64)
-    sites: List[str] = []
-    workers = np.empty(n, np.int64)
-    methods: List[str] = []
-    streams = np.empty(n, np.int64)
-    for i, r in enumerate(reqs):
-        p = path_ids.setdefault(r.path, len(path_ids))
-        if p == len(sizes):
-            sizes.append(0)
-        sizes[p] = max(sizes[p], r.size)
-        pid[i] = p
-        at[i] = r.at
-        sites.append(r.site or default_site)
-        workers[i] = r.worker
-        methods.append(r.method)
-        streams[i] = r.streams or spec.streams
-    P = len(path_ids)
-    paths = list(path_ids)
-    size = np.asarray(sizes, np.int64)
-    found = size > 0
+        # ---- request arrays (original order) --------------------------------
+        path_ids: Dict[str, int] = {}
+        sizes: List[int] = []
+        pid = np.empty(n, np.int64)
+        at = np.empty(n, np.float64)
+        sites: List[str] = []
+        workers = np.empty(n, np.int64)
+        methods: List[str] = []
+        streams = np.empty(n, np.int64)
+        for i, r in enumerate(reqs):
+            p = path_ids.setdefault(r.path, len(path_ids))
+            if p == len(sizes):
+                sizes.append(0)
+            sizes[p] = max(sizes[p], r.size)
+            pid[i] = p
+            at[i] = r.at
+            sites.append(r.site or default_site)
+            workers[i] = r.worker
+            methods.append(r.method)
+            streams[i] = r.streams or spec.streams
+        P = len(path_ids)
+        paths = list(path_ids)
+        size = np.asarray(sizes, np.int64)
+        found = size > 0
 
-    owners: List[Optional[object]] = []
-    for p in range(P):
-        owner = fed.resolve_origin(paths[p])
-        if owner is None and found[p]:
-            return None  # serial run_scenario raises KeyError here
-        owners.append(owner)
-    # chunk count per path, from the owning origin's chunking (what a
-    # serial run_scenario's publish would have produced)
-    nchunks = np.asarray(
-        [-(-size[p] // owners[p].chunk_size) if found[p] else 1
-         for p in range(P)], np.int64)
+        owners: List[Optional[object]] = []
+        for p in range(P):
+            owner = fed.resolve_origin(paths[p])
+            if owner is None and found[p]:
+                return None  # serial run_scenario raises KeyError here
+            owners.append(owner)
+        # chunk count per path, from the owning origin's chunking (what a
+        # serial run_scenario's publish would have produced)
+        nchunks = np.asarray(
+            [-(-size[p] // owners[p].chunk_size) if found[p] else 1
+             for p in range(P)], np.int64)
 
-    site_ids: Dict[str, int] = {}
-    sid = np.asarray([site_ids.setdefault(s, len(site_ids)) for s in sites])
-    site_names = list(site_ids)
-    method_is_direct = np.asarray([m == "direct" for m in methods])
+        site_ids: Dict[str, int] = {}
+        sid = np.asarray([site_ids.setdefault(s, len(site_ids))
+                          for s in sites])
+        site_names = list(site_ids)
+        method_is_direct = np.asarray([m == "direct" for m in methods])
 
-    # ---- routing (liveness-independent chains, shared across cells) --------
-    cache_ids = {name: ci for ci, name in enumerate(fed.caches)}
-    chains: Dict[Tuple[int, int], List[int]] = {}
-    for si, pi in {(int(s), int(p))
-                   for s, p, d in zip(sid, pid, method_is_direct) if not d}:
-        names = _ranked_names(fed, state, site_names[si], paths[pi])
-        chains[(si, pi)] = [cache_ids[nm] for nm in names]
-    group_of = {c.name: g for g in fed.groups.values() for c in g.members}
-    # primary cache (nearest group's ring owner) per chain — the one
-    # whose liveness decides a counted group failover.
-    primary: Dict[Tuple[int, int], int] = {}
-    cache_names = list(fed.caches)
-    for key, chain in chains.items():
-        prim = -1
-        for ci in chain:
-            if cache_names[ci] in group_of:
-                prim = ci
-                break
-        primary[key] = prim if prim >= 0 else (chain[0] if chain else -1)
-
-    # ---- network constants (per site / cache / owner) ----------------------
-    net, topo = fed.net, fed.topology
-    wnode: Dict[Tuple[int, int], str] = {}
-    for si, w in {(int(s), int(w)) for s, w in zip(sid, workers)}:
-        wnode[(si, w)] = _worker_node(fed, site_names[si], w)
-
-    # ---- chronological epochs between outage events ------------------------
-    order = np.argsort(at, kind="stable")
-    op = np.empty(n, np.int64)               # arrival rank per request
-    op[order] = np.arange(n)
-    events = list(spec.outages) if spec.outages is not None else []
-    for ev in events:
-        if ev.cache not in group_of and ev.cache not in fed.caches:
-            raise KeyError(ev.cache)  # same failure as the serial plane
-    alive = np.ones(len(cache_ids), bool)
-    was_counted = {"outages": 0, "recoveries": 0}
-    # cold-restart positions per cache, as arrival ranks: requests with
-    # op >= the recorded rank see that cache's disk wiped
-    resets: Dict[int, List[int]] = {}
-    processed = 0
-
-    chosen = np.full(n, -1, np.int64)        # serving cache (-1: none)
-    parent_of = np.full(n, -1, np.int64)     # epoch-alive fill parent
-    dead_before = np.zeros(n, np.int64)
-    primary_dead = np.zeros(n, bool)
-    fallback = np.zeros(n, bool)
-    ok = np.ones(n, bool)
-
-    caches = list(fed.caches.values())
-    pchains: Dict[Tuple[int, int], List[int]] = {}
-
-    def _parent_chain(serve_ci: int, pi: int) -> Sequence[int]:
-        """The serving cache's parent-tier fill chain for one path —
-        consistent-hash order, liveness-independent (aliveness is the
-        per-epoch filter, exactly as ``CacheServer.parent_caches``)."""
-        pg = caches[serve_ci].parent_group
-        if pg is None:
-            return ()
-        key = (id(pg), pi)
-        chain = pchains.get(key)
-        if chain is None:
-            chain = pchains[key] = [cache_ids[c.name]
-                                    for c in pg.fill_chain(paths[pi])]
-        return chain
-
-    def apply_event(ev) -> None:
-        ci = cache_ids[ev.cache]
-        if ev.action == "down":
-            if alive[ci]:
-                alive[ci] = False
-                if ev.cache in group_of:
-                    was_counted["outages"] += 1
-        else:
-            if not alive[ci]:
-                alive[ci] = True
-                if ev.cache in group_of:
-                    was_counted["recoveries"] += 1
-                if ev.cold:
-                    resets.setdefault(ci, []).append(processed)
-
-    def run_epoch(idx: np.ndarray) -> None:
-        """Vectorized routing for one liveness epoch (``idx`` are
-        request indices in arrival order).  Hit/miss is *not* resolved
-        here — that is the kernels' job, per cell — only which cache
-        serves whom."""
-        if idx.size == 0:
-            return
-        allstash = idx[~method_is_direct[idx]]
-        stash = allstash[found[pid[allstash]]]
-        # liveness-resolved serving cache per (site, path) this epoch
+        # ---- routing (liveness-independent chains, shared across cells) -----
+        cache_ids = {name: ci for ci, name in enumerate(fed.caches)}
+        chains: Dict[Tuple[int, int], List[int]] = {}
+        for si, pi in {(int(s), int(p)) for s, p, d
+                       in zip(sid, pid, method_is_direct) if not d}:
+            names = _ranked_names(fed, state, site_names[si], paths[pi])
+            chains[(si, pi)] = [cache_ids[nm] for nm in names]
+        group_of = {c.name: g for g in fed.groups.values() for c in g.members}
+        # primary cache (nearest group's ring owner) per chain — the one
+        # whose liveness decides a counted group failover.
+        primary: Dict[Tuple[int, int], int] = {}
+        cache_names = list(fed.caches)
         for key, chain in chains.items():
-            si, pi = key
-            sel = allstash[(sid[allstash] == si) & (pid[allstash] == pi)]
-            if sel.size == 0:
-                continue
-            # every stash request — found or not — walks the ranked
-            # chain, so a dead ring owner counts its group failovers
-            primary_dead[sel] = (primary[key] >= 0
-                                 and not alive[primary[key]])
-            fsel = sel[found[pid[sel]]]
-            if fsel.size == 0:
-                continue
-            serve, dead = -1, 0
+            prim = -1
             for ci in chain:
-                if alive[ci]:
-                    serve = ci
+                if cache_names[ci] in group_of:
+                    prim = ci
                     break
-                dead += 1
-            chosen[fsel] = serve
-            dead_before[fsel] = dead
-            if serve >= 0:
-                par = -1
-                for qi in _parent_chain(serve, pi):
-                    if alive[qi] and qi != serve:
-                        par = qi
-                        break
-                parent_of[fsel] = par
-        fallback[stash] = chosen[stash] < 0
-        # not-found stash requests fail visibly, as on the serial plane
-        nf = idx[~method_is_direct[idx] & ~found[pid[idx]]]
-        ok[nf] = False
-        direct = idx[method_is_direct[idx]]
-        ok[direct] = found[pid[direct]]
+            primary[key] = prim if prim >= 0 else (chain[0] if chain else -1)
 
-    ei = 0
-    pending: List[int] = []
-    for i in order:
-        while ei < len(events) and events[ei].time <= at[i]:
-            run_epoch(np.asarray(pending, np.int64))
-            processed += len(pending)
-            pending = []
+        # ---- network constants (per site / cache / owner) -------------------
+        net, topo = fed.net, fed.topology
+        wnode: Dict[Tuple[int, int], str] = {}
+        for si, w in {(int(s), int(w)) for s, w in zip(sid, workers)}:
+            wnode[(si, w)] = _worker_node(fed, site_names[si], w)
+
+        # ---- chronological epochs between outage events ---------------------
+        order = np.argsort(at, kind="stable")
+        op = np.empty(n, np.int64)               # arrival rank per request
+        op[order] = np.arange(n)
+        events = list(spec.outages) if spec.outages is not None else []
+        for ev in events:
+            if ev.cache not in group_of and ev.cache not in fed.caches:
+                raise KeyError(ev.cache)  # same failure as the serial plane
+        alive = np.ones(len(cache_ids), bool)
+        was_counted = {"outages": 0, "recoveries": 0}
+        # cold-restart positions per cache, as arrival ranks: requests with
+        # op >= the recorded rank see that cache's disk wiped
+        resets: Dict[int, List[int]] = {}
+        processed = 0
+
+        chosen = np.full(n, -1, np.int64)        # serving cache (-1: none)
+        parent_of = np.full(n, -1, np.int64)     # epoch-alive fill parent
+        dead_before = np.zeros(n, np.int64)
+        primary_dead = np.zeros(n, bool)
+        fallback = np.zeros(n, bool)
+        ok = np.ones(n, bool)
+
+        caches = list(fed.caches.values())
+        pchains: Dict[Tuple[int, int], List[int]] = {}
+
+        def _parent_chain(serve_ci: int, pi: int) -> Sequence[int]:
+            """The serving cache's parent-tier fill chain for one path —
+            consistent-hash order, liveness-independent (aliveness is the
+            per-epoch filter, exactly as ``CacheServer.parent_caches``)."""
+            pg = caches[serve_ci].parent_group
+            if pg is None:
+                return ()
+            key = (id(pg), pi)
+            chain = pchains.get(key)
+            if chain is None:
+                chain = pchains[key] = [cache_ids[c.name]
+                                        for c in pg.fill_chain(paths[pi])]
+            return chain
+
+        def apply_event(ev) -> None:
+            ci = cache_ids[ev.cache]
+            if ev.action == "down":
+                if alive[ci]:
+                    alive[ci] = False
+                    if ev.cache in group_of:
+                        was_counted["outages"] += 1
+            else:
+                if not alive[ci]:
+                    alive[ci] = True
+                    if ev.cache in group_of:
+                        was_counted["recoveries"] += 1
+                    if ev.cold:
+                        resets.setdefault(ci, []).append(processed)
+
+        def run_epoch(idx: np.ndarray) -> None:
+            """Vectorized routing for one liveness epoch (``idx`` are
+            request indices in arrival order).  Hit/miss is *not* resolved
+            here — that is the kernels' job, per cell — only which cache
+            serves whom."""
+            if idx.size == 0:
+                return
+            allstash = idx[~method_is_direct[idx]]
+            stash = allstash[found[pid[allstash]]]
+            # liveness-resolved serving cache per (site, path) this epoch
+            for key, chain in chains.items():
+                si, pi = key
+                sel = allstash[(sid[allstash] == si) & (pid[allstash] == pi)]
+                if sel.size == 0:
+                    continue
+                # every stash request — found or not — walks the ranked
+                # chain, so a dead ring owner counts its group failovers
+                primary_dead[sel] = (primary[key] >= 0
+                                     and not alive[primary[key]])
+                fsel = sel[found[pid[sel]]]
+                if fsel.size == 0:
+                    continue
+                serve, dead = -1, 0
+                for ci in chain:
+                    if alive[ci]:
+                        serve = ci
+                        break
+                    dead += 1
+                chosen[fsel] = serve
+                dead_before[fsel] = dead
+                if serve >= 0:
+                    par = -1
+                    for qi in _parent_chain(serve, pi):
+                        if alive[qi] and qi != serve:
+                            par = qi
+                            break
+                    parent_of[fsel] = par
+            fallback[stash] = chosen[stash] < 0
+            # not-found stash requests fail visibly, as on the serial plane
+            nf = idx[~method_is_direct[idx] & ~found[pid[idx]]]
+            ok[nf] = False
+            direct = idx[method_is_direct[idx]]
+            ok[direct] = found[pid[direct]]
+
+        ei = 0
+        pending: List[int] = []
+        for i in order:
+            while ei < len(events) and events[ei].time <= at[i]:
+                run_epoch(np.asarray(pending, np.int64))
+                processed += len(pending)
+                pending = []
+                apply_event(events[ei])
+                ei += 1
+            pending.append(int(i))
+        run_epoch(np.asarray(pending, np.int64))
+        processed += len(pending)
+        while ei < len(events):
             apply_event(events[ei])
             ei += 1
-        pending.append(int(i))
-    run_epoch(np.asarray(pending, np.int64))
-    processed += len(pending)
-    while ei < len(events):
-        apply_event(events[ei])
-        ei += 1
-    served_mask = chosen >= 0
+        served_mask = chosen >= 0
 
-    # ---- when does each cache learn an object's size? ----------------------
-    # Admission sees the whole object only once the serving cache has
-    # the meta cached — and only the liveness-independent chain *head*
-    # is ever asked to locate it (``StashClient._meta`` returns at the
-    # first non-None ``locate_meta``).  So a non-head cache serving
-    # under an outage judges admission by the chunk payload until some
-    # request whose chain it heads has touched the path.
-    meta_rank: Dict[Tuple[int, int], int] = {}
-    for i in range(n):
-        if method_is_direct[i] or not found[pid[i]]:
-            continue
-        chain = chains.get((int(sid[i]), int(pid[i])))
-        if chain:
-            key = (chain[0], int(pid[i]))
-            r = meta_rank.get(key)
-            if r is None or op[i] < r:
-                meta_rank[key] = int(op[i])
+        # ---- when does each cache learn an object's size? -------------------
+        # Admission sees the whole object only once the serving cache has
+        # the meta cached — and only the liveness-independent chain *head*
+        # is ever asked to locate it (``StashClient._meta`` returns at the
+        # first non-None ``locate_meta``).  So a non-head cache serving
+        # under an outage judges admission by the chunk payload until some
+        # request whose chain it heads has touched the path.
+        meta_rank: Dict[Tuple[int, int], int] = {}
+        for i in range(n):
+            if method_is_direct[i] or not found[pid[i]]:
+                continue
+            chain = chains.get((int(sid[i]), int(pid[i])))
+            if chain:
+                key = (chain[0], int(pid[i]))
+                r = meta_rank.get(key)
+                if r is None or op[i] < r:
+                    meta_rank[key] = int(op[i])
 
-    # ---- timing constants + per-cache chunk reference streams --------------
-    lookup = fed.geoip.lookup_latency
-    bw_serve: Dict[Tuple[int, int], float] = {}
-    rtt_serve: Dict[Tuple[int, int], float] = {}
-    rpc_red: Dict[int, float] = {}
-    bw_pull: Dict[Tuple[int, int], float] = {}
-    rtt_pull: Dict[Tuple[int, int], float] = {}
-    bw_fill: Dict[Tuple[int, int], float] = {}
-    rtt_fill: Dict[Tuple[int, int], float] = {}
-    red_node = fed.redirectors.members[0].node.name
-    nreq = nchunks[pid]
-    serve_base = np.zeros(n, np.float64)   # hit-path seconds per request
-    streams_by_cache: Dict[int, _CacheStream] = {}
-    key_ids: Dict[int, Dict[Tuple[int, int], int]] = {}
-    last_ref: Dict[int, Dict[int, Tuple[int, int]]] = {}
-    last_seg: Dict[int, int] = {}
-    Cmax = int(nchunks.max()) if P else 1
-    gpos = 0
+        # ---- timing constants + per-cache chunk reference streams -----------
+        lookup = fed.geoip.lookup_latency
+        bw_serve: Dict[Tuple[int, int], float] = {}
+        rtt_serve: Dict[Tuple[int, int], float] = {}
+        rpc_red: Dict[int, float] = {}
+        bw_pull: Dict[Tuple[int, int], float] = {}
+        rtt_pull: Dict[Tuple[int, int], float] = {}
+        bw_fill: Dict[Tuple[int, int], float] = {}
+        rtt_fill: Dict[Tuple[int, int], float] = {}
+        red_node = fed.redirectors.members[0].node.name
+        nreq = nchunks[pid]
+        serve_base = np.zeros(n, np.float64)   # hit-path seconds per request
+        streams_by_cache: Dict[int, _CacheStream] = {}
+        key_ids: Dict[int, Dict[Tuple[int, int], int]] = {}
+        last_ref: Dict[int, Dict[int, Tuple[int, int]]] = {}
+        last_seg: Dict[int, int] = {}
+        Cmax = int(nchunks.max()) if P else 1
+        gpos = 0
 
-    def _chunk_len(p: int, j: int) -> int:
-        cs = owners[p].chunk_size
-        return int(min(cs, size[p] - j * cs)) if size[p] else 0
+        def _chunk_len(p: int, j: int) -> int:
+            cs = owners[p].chunk_size
+            return int(min(cs, size[p] - j * cs)) if size[p] else 0
 
-    for i in order:
-        if chosen[i] < 0:
-            continue
-        i, ci, p = int(i), int(chosen[i]), int(pid[i])
-        si = int(sid[i])
-        wn = wnode[(si, int(workers[i]))]
-        cnode = caches[ci].node.name
-        k = (ci, si)
-        if k not in bw_serve:
-            bw_serve[k] = net.effective_bandwidth(cnode, wn, streams=8)
-            rtt_serve[k] = topo.rtt(cnode, wn)
-        pk = (ci, p)
-        if pk not in bw_pull:
-            onode = owners[p].node.name
-            bw_pull[pk] = net.effective_bandwidth(onode, cnode, streams=8)
-            rtt_pull[pk] = topo.rtt(onode, cnode)
-            if ci not in rpc_red:
-                rpc_red[ci] = net.rpc_time(cnode, red_node)
-        q = int(parent_of[i])
-        if q >= 0:
-            # miss fills cache-to-cache: parent -> this cache transfer,
-            # plus the parent's own redirector RPC + origin pull if the
-            # parent misses too (resolved by the round-2 kernels)
-            pnode = caches[q].node.name
-            fk = (q, ci)
-            if fk not in bw_fill:
-                bw_fill[fk] = net.effective_bandwidth(pnode, cnode,
-                                                      streams=8)
-                rtt_fill[fk] = topo.rtt(pnode, cnode)
-            qk = (q, p)
-            if qk not in bw_pull:
-                onode = owners[p].node.name
-                bw_pull[qk] = net.effective_bandwidth(onode, pnode,
-                                                      streams=8)
-                rtt_pull[qk] = topo.rtt(onode, pnode)
-            if q not in rpc_red:
-                rpc_red[q] = net.rpc_time(pnode, red_node)
-            l2_base = rpc_red[q] + rtt_pull[qk]
-            qcuts = resets.get(q, ())
-            qseg = sum(1 for c in qcuts if c <= op[i])
-        stream = streams_by_cache.get(ci)
-        if stream is None:
-            stream = streams_by_cache[ci] = _CacheStream()
-            key_ids[ci] = {}
-            last_ref[ci] = {}
-            last_seg[ci] = 0
-        cuts = resets.get(ci, ())
-        seg = sum(1 for c in cuts if c <= op[i])
-        fresh_seg = seg != last_seg[ci] and len(stream.req) > 0
-        last_seg[ci] = seg
-        known = meta_rank.get((ci, p), n + 1) <= op[i]
-        # the *parent's* admission basis: the child forwards its located
-        # object size upstream; failing that the parent falls back to
-        # its own meta knowledge, then the chunk payload
-        l2_known = known or (q >= 0
-                             and meta_rank.get((q, p), n + 1) <= op[i])
-        secs = lookup + nreq[i] * rtt_serve[k]
-        miss_base = rpc_red[ci] + rtt_pull[pk]
-        for j in range(int(nchunks[p])):
-            csize = _chunk_len(p, j)
-            kid = key_ids[ci].setdefault((p, j), len(key_ids[ci]))
-            if kid == len(stream.key_sizes):
-                stream.key_sizes.append(csize)
-            prev_entry = last_ref[ci].get(kid)
-            prev = (prev_entry[0] if prev_entry is not None
-                    and prev_entry[1] == seg else -1)
-            last_ref[ci][kid] = (len(stream.req), seg)
-            basis = int(size[p]) if known else csize
-            cap = caches[ci].serve_rate_cap(basis)
-            secs += csize / (min(bw_serve[k], cap) if cap else bw_serve[k])
-            stream.req.append(i)
-            stream.keys.append(kid)
-            stream.size.append(csize)
-            stream.prev.append(prev)
-            stream.reset.append(fresh_seg and j == 0)
-            stream.seg.append(seg)
-            stream.eff_obj.append(int(size[p]) if known else csize)
-            stream.miss_sec.append(miss_base + csize / bw_pull[pk])
-            stream.parent_ci.append(q)
-            stream.gpos.append(gpos)
-            stream.pj.append(p * Cmax + j)
-            if q >= 0:
-                stream.fill_sec.append(rtt_fill[fk] + csize / bw_fill[fk])
-                stream.l2_sec.append(l2_base + csize / bw_pull[qk])
-                stream.l2_eff.append(int(size[p]) if l2_known else csize)
-                stream.l2_seg.append(qseg)
-            else:
-                stream.fill_sec.append(0.0)
-                stream.l2_sec.append(0.0)
-                stream.l2_eff.append(csize)
-                stream.l2_seg.append(0)
-            gpos += 1
-        serve_base[i] = secs
-
-    direct_like = ok & (fallback | method_is_direct)
-    direct_sec = np.zeros(n, np.float64)
-    for i in np.nonzero(direct_like)[0]:
-        onode = owners[pid[i]].node.name
-        wn = wnode[(int(sid[i]), int(workers[i]))]
-        direct_sec[i] = net.transfer_time(onode, wn, int(size[pid[i]]),
-                                          streams=int(streams[i]))
-
-    for stream in streams_by_cache.values():
-        stream.arrays()
-    # The distance/replay scans are O(N) per reference (O(N²) per
-    # stream); surface the longest stream so a sweep that drifts into
-    # that regime is diagnosable from report.solver, and the shortest,
-    # which says whether every cache saw a realistic stream.
-    if streams_by_cache:
-        lengths = [len(s.req) for s in streams_by_cache.values()]
-        telemetry["max_stream_refs"] = max(
-            telemetry.get("max_stream_refs", 0), max(lengths))
-        telemetry["min_stream_refs"] = min(
-            telemetry.get("min_stream_refs", min(lengths)), min(lengths))
-
-    # ---- cell-independent counters and flow constants ----------------------
-    cache_failovers = int((nreq[served_mask] * dead_before[served_mask])
-                          .sum())
-    ranked_len = np.asarray([len(chains.get((int(s), int(p)), []))
-                             for s, p in zip(sid, pid)])
-    cache_failovers += int(2 * ranked_len[fallback].sum())
-    # ranked-cache calls per request: n+2 (served), 6 (fallback: two
-    # method attempts of meta+monitor+chunk0), 2 (not found: meta per
-    # method) — each counting one group failover iff the nearest ring
-    # owner is dead.
-    stash_mask = ~method_is_direct
-    calls = np.zeros(n, np.int64)
-    calls[served_mask] = nreq[served_mask] + 2
-    calls[fallback] = 6
-    calls[stash_mask & ~ok] = 2
-
-    serve_flow: Dict[int, Tuple[List, float]] = {}
-    pull_flow: Dict[Tuple[int, int], Tuple[List, float]] = {}
-    for i in range(n):
-        if not ok[i]:
-            continue
-        p = int(pid[i])
-        wn = wnode[(int(sid[i]), int(workers[i]))]
-        if method_is_direct[i] or fallback[i]:
-            src = owners[p].node.name
-            links = topo.path(src, wn)
-            cap_f = max(1, int(streams[i])) * net.per_stream_cap(
-                topo.rtt(src, wn))
-        else:
-            ci = int(chosen[i])
+        for i in order:
+            if chosen[i] < 0:
+                continue
+            i, ci, p = int(i), int(chosen[i]), int(pid[i])
+            si = int(sid[i])
+            wn = wnode[(si, int(workers[i]))]
             cnode = caches[ci].node.name
+            k = (ci, si)
+            if k not in bw_serve:
+                bw_serve[k] = net.effective_bandwidth(cnode, wn, streams=8)
+                rtt_serve[k] = topo.rtt(cnode, wn)
+            pk = (ci, p)
+            if pk not in bw_pull:
+                onode = owners[p].node.name
+                bw_pull[pk] = net.effective_bandwidth(onode, cnode, streams=8)
+                rtt_pull[pk] = topo.rtt(onode, cnode)
+                if ci not in rpc_red:
+                    rpc_red[ci] = net.rpc_time(cnode, red_node)
             q = int(parent_of[i])
             if q >= 0:
-                # tiered miss path: child pulls from its parent, the
-                # parent (on its own miss) pulls from the origin
+                # miss fills cache-to-cache: parent -> this cache transfer,
+                # plus the parent's own redirector RPC + origin pull if the
+                # parent misses too (resolved by the round-2 kernels)
                 pnode = caches[q].node.name
-                if (ci, p) not in pull_flow:
-                    pull_flow[(ci, p)] = (
-                        topo.path(pnode, cnode),
-                        4 * net.per_stream_cap(topo.rtt(pnode, cnode)))
-                if (q, p) not in pull_flow:
+                fk = (q, ci)
+                if fk not in bw_fill:
+                    bw_fill[fk] = net.effective_bandwidth(pnode, cnode,
+                                                          streams=8)
+                    rtt_fill[fk] = topo.rtt(pnode, cnode)
+                qk = (q, p)
+                if qk not in bw_pull:
                     onode = owners[p].node.name
-                    pull_flow[(q, p)] = (
-                        topo.path(onode, pnode),
-                        4 * net.per_stream_cap(topo.rtt(onode, pnode)))
-            elif (ci, p) not in pull_flow:
-                onode = owners[p].node.name
-                pull_flow[(ci, p)] = (
-                    topo.path(onode, cnode),
-                    4 * net.per_stream_cap(topo.rtt(onode, cnode)))
-            links = topo.path(cnode, wn)
-            cap_f = max(1, spec.streams) * net.per_stream_cap(
-                topo.rtt(cnode, wn))
-            rc = caches[ci].serve_rate_cap(int(size[p]))
-            if rc:
-                cap_f = min(cap_f, rc)
-        serve_flow[i] = (links, cap_f)
+                    bw_pull[qk] = net.effective_bandwidth(onode, pnode,
+                                                          streams=8)
+                    rtt_pull[qk] = topo.rtt(onode, pnode)
+                if q not in rpc_red:
+                    rpc_red[q] = net.rpc_time(pnode, red_node)
+                l2_base = rpc_red[q] + rtt_pull[qk]
+                qcuts = resets.get(q, ())
+                qseg = sum(1 for c in qcuts if c <= op[i])
+            stream = streams_by_cache.get(ci)
+            if stream is None:
+                stream = streams_by_cache[ci] = _CacheStream()
+                key_ids[ci] = {}
+                last_ref[ci] = {}
+                last_seg[ci] = 0
+            cuts = resets.get(ci, ())
+            seg = sum(1 for c in cuts if c <= op[i])
+            fresh_seg = seg != last_seg[ci] and len(stream.req) > 0
+            last_seg[ci] = seg
+            known = meta_rank.get((ci, p), n + 1) <= op[i]
+            # the *parent's* admission basis: the child forwards its located
+            # object size upstream; failing that the parent falls back to
+            # its own meta knowledge, then the chunk payload
+            l2_known = known or (q >= 0
+                                 and meta_rank.get((q, p), n + 1) <= op[i])
+            secs = lookup + nreq[i] * rtt_serve[k]
+            miss_base = rpc_red[ci] + rtt_pull[pk]
+            for j in range(int(nchunks[p])):
+                csize = _chunk_len(p, j)
+                kid = key_ids[ci].setdefault((p, j), len(key_ids[ci]))
+                if kid == len(stream.key_sizes):
+                    stream.key_sizes.append(csize)
+                prev_entry = last_ref[ci].get(kid)
+                prev = (prev_entry[0] if prev_entry is not None
+                        and prev_entry[1] == seg else -1)
+                last_ref[ci][kid] = (len(stream.req), seg)
+                basis = int(size[p]) if known else csize
+                cap = caches[ci].serve_rate_cap(basis)
+                secs += csize / (min(bw_serve[k], cap) if cap else bw_serve[k])
+                stream.req.append(i)
+                stream.keys.append(kid)
+                stream.size.append(csize)
+                stream.prev.append(prev)
+                stream.reset.append(fresh_seg and j == 0)
+                stream.seg.append(seg)
+                stream.eff_obj.append(int(size[p]) if known else csize)
+                stream.miss_sec.append(miss_base + csize / bw_pull[pk])
+                stream.parent_ci.append(q)
+                stream.gpos.append(gpos)
+                stream.pj.append(p * Cmax + j)
+                if q >= 0:
+                    stream.fill_sec.append(rtt_fill[fk] + csize / bw_fill[fk])
+                    stream.l2_sec.append(l2_base + csize / bw_pull[qk])
+                    stream.l2_eff.append(int(size[p]) if l2_known else csize)
+                    stream.l2_seg.append(qseg)
+                else:
+                    stream.fill_sec.append(0.0)
+                    stream.l2_sec.append(0.0)
+                    stream.l2_eff.append(csize)
+                    stream.l2_seg.append(0)
+                gpos += 1
+            serve_base[i] = secs
 
-    fill_targets: Set[int] = set()
-    for s in streams_by_cache.values():
-        fill_targets.update(int(x) for x in np.unique(s.parent_ci)
-                            if x >= 0)
-    for q in fill_targets:
-        sq = streams_by_cache.get(q)
-        if sq is not None and (sq.parent_ci >= 0).any():
-            # a fill target that itself fills upstream needs a third
-            # kernel round; replay such cells serially
-            return None
+        for stream in streams_by_cache.values():
+            stream.arrays()
+        # The distance/replay scans are O(N) per reference (O(N²) per
+        # stream); surface the longest stream so a sweep that drifts into
+        # that regime is diagnosable from report.solver, and the shortest,
+        # which says whether every cache saw a realistic stream.
+        if streams_by_cache:
+            lengths = [len(s.req) for s in streams_by_cache.values()]
+            telemetry["max_stream_refs"] = max(
+                telemetry.get("max_stream_refs", 0), max(lengths))
+            telemetry["min_stream_refs"] = min(
+                telemetry.get("min_stream_refs", min(lengths)), min(lengths))
+            telemetry["stream_refs"] = (telemetry.get("stream_refs", 0)
+                                        + sum(lengths))
 
-    routing = _CellRouting()
-    routing.n = n
-    routing.paths = paths
-    routing.size = size
-    routing.pid = pid
-    routing.at = at
-    routing.nchunks = nchunks
-    routing.nreq = nreq
-    routing.methods = methods
-    routing.method_is_direct = method_is_direct
-    routing.owner_names = [o.name if o is not None else "" for o in owners]
-    routing.cache_names = cache_names
-    routing.chosen = chosen
-    routing.fallback = fallback
-    routing.ok = ok
-    routing.served_mask = served_mask
-    routing.serve_base = serve_base
-    routing.direct_sec = direct_sec
-    routing.streams = streams_by_cache
-    routing.fill_targets = fill_targets
-    routing.cache_tier = [c.tier for c in caches]
-    routing.all_tiers = sorted({c.tier for c in caches})
-    routing.Cmax = Cmax
-    routing.l2_cache = {}
-    routing.counters = {
-        "cache_failovers": cache_failovers,
-        "group_failovers": int(calls[primary_dead].sum()),
-        "origin_fallbacks": int(fallback.sum()),
-        "outages": was_counted["outages"],
-        "recoveries": was_counted["recoveries"],
-    }
-    routing.serve_flow = serve_flow
-    routing.pull_flow = pull_flow
-    # byte counters that never depend on cache policy
-    sz_int = size[pid]
-    moved = ok & (served_mask | fallback | method_is_direct)
-    routing.bytes_moved = int(sz_int[moved].sum())
-    routing.direct_egress = int(
-        sz_int[ok & (fallback | method_is_direct)].sum())
-    return routing
+    with TraceAnnotation(spans.ROUTE_FLOWS):
+        direct_like = ok & (fallback | method_is_direct)
+        direct_sec = np.zeros(n, np.float64)
+        for i in np.nonzero(direct_like)[0]:
+            onode = owners[pid[i]].node.name
+            wn = wnode[(int(sid[i]), int(workers[i]))]
+            direct_sec[i] = net.transfer_time(onode, wn, int(size[pid[i]]),
+                                              streams=int(streams[i]))
+
+        # ---- cell-independent counters and flow constants -------------------
+        cache_failovers = int((nreq[served_mask] * dead_before[served_mask])
+                              .sum())
+        ranked_len = np.asarray([len(chains.get((int(s), int(p)), []))
+                                 for s, p in zip(sid, pid)])
+        cache_failovers += int(2 * ranked_len[fallback].sum())
+        # ranked-cache calls per request: n+2 (served), 6 (fallback: two
+        # method attempts of meta+monitor+chunk0), 2 (not found: meta per
+        # method) — each counting one group failover iff the nearest ring
+        # owner is dead.
+        stash_mask = ~method_is_direct
+        calls = np.zeros(n, np.int64)
+        calls[served_mask] = nreq[served_mask] + 2
+        calls[fallback] = 6
+        calls[stash_mask & ~ok] = 2
+
+        serve_flow: Dict[int, Tuple[List, float]] = {}
+        pull_flow: Dict[Tuple[int, int], Tuple[List, float]] = {}
+        for i in range(n):
+            if not ok[i]:
+                continue
+            p = int(pid[i])
+            wn = wnode[(int(sid[i]), int(workers[i]))]
+            if method_is_direct[i] or fallback[i]:
+                src = owners[p].node.name
+                links = topo.path(src, wn)
+                cap_f = max(1, int(streams[i])) * net.per_stream_cap(
+                    topo.rtt(src, wn))
+            else:
+                ci = int(chosen[i])
+                cnode = caches[ci].node.name
+                q = int(parent_of[i])
+                if q >= 0:
+                    # tiered miss path: child pulls from its parent, the
+                    # parent (on its own miss) pulls from the origin
+                    pnode = caches[q].node.name
+                    if (ci, p) not in pull_flow:
+                        pull_flow[(ci, p)] = (
+                            topo.path(pnode, cnode),
+                            4 * net.per_stream_cap(topo.rtt(pnode, cnode)))
+                    if (q, p) not in pull_flow:
+                        onode = owners[p].node.name
+                        pull_flow[(q, p)] = (
+                            topo.path(onode, pnode),
+                            4 * net.per_stream_cap(topo.rtt(onode, pnode)))
+                elif (ci, p) not in pull_flow:
+                    onode = owners[p].node.name
+                    pull_flow[(ci, p)] = (
+                        topo.path(onode, cnode),
+                        4 * net.per_stream_cap(topo.rtt(onode, cnode)))
+                links = topo.path(cnode, wn)
+                cap_f = max(1, spec.streams) * net.per_stream_cap(
+                    topo.rtt(cnode, wn))
+                rc = caches[ci].serve_rate_cap(int(size[p]))
+                if rc:
+                    cap_f = min(cap_f, rc)
+            serve_flow[i] = (links, cap_f)
+
+        fill_targets: Set[int] = set()
+        for s in streams_by_cache.values():
+            fill_targets.update(int(x) for x in np.unique(s.parent_ci)
+                                if x >= 0)
+        for q in fill_targets:
+            sq = streams_by_cache.get(q)
+            if sq is not None and (sq.parent_ci >= 0).any():
+                # a fill target that itself fills upstream needs a third
+                # kernel round; replay such cells serially
+                return None
+
+        routing = _CellRouting()
+        routing.n = n
+        routing.paths = paths
+        routing.size = size
+        routing.pid = pid
+        routing.at = at
+        routing.nchunks = nchunks
+        routing.nreq = nreq
+        routing.methods = methods
+        routing.method_is_direct = method_is_direct
+        routing.owner_names = [o.name if o is not None else "" for o in owners]
+        routing.cache_names = cache_names
+        routing.chosen = chosen
+        routing.fallback = fallback
+        routing.ok = ok
+        routing.served_mask = served_mask
+        routing.serve_base = serve_base
+        routing.direct_sec = direct_sec
+        routing.streams = streams_by_cache
+        routing.fill_targets = fill_targets
+        routing.cache_tier = [c.tier for c in caches]
+        routing.all_tiers = sorted({c.tier for c in caches})
+        routing.Cmax = Cmax
+        routing.l2_cache = {}
+        routing.counters = {
+            "cache_failovers": cache_failovers,
+            "group_failovers": int(calls[primary_dead].sum()),
+            "origin_fallbacks": int(fallback.sum()),
+            "outages": was_counted["outages"],
+            "recoveries": was_counted["recoveries"],
+        }
+        routing.serve_flow = serve_flow
+        routing.pull_flow = pull_flow
+        # byte counters that never depend on cache policy
+        sz_int = size[pid]
+        moved = ok & (served_mask | fallback | method_is_direct)
+        routing.bytes_moved = int(sz_int[moved].sum())
+        routing.direct_egress = int(
+            sz_int[ok & (fallback | method_is_direct)].sum())
+        return routing
 
 
 def _tally(telemetry: Dict, kind: str, stats: Dict) -> None:
@@ -2068,56 +2076,57 @@ def _resolve_distances(wanted: Sequence[Tuple[_CacheStream, bytes,
     A variant is the stream restricted to one admission filter class
     (``mask`` marks admitted keys; refused keys never perturb the LRU
     stack, so dropping their references is exact)."""
-    from repro.kernels.stack_distance import stack_distances_batch
-    pending: List[Tuple[_CacheStream, bytes, np.ndarray]] = []
-    seen_sigs: Set[Tuple[int, bytes]] = set()
-    for stream, sig, mask in wanted:
-        if sig in stream.variants or (id(stream), sig) in seen_sigs:
-            continue
-        seen_sigs.add((id(stream), sig))
-        pending.append((stream, sig, mask))
-    if not pending:
-        return
-    problems = []
-    selections = []
-    for stream, sig, mask in pending:
-        sel = np.nonzero(mask[stream.keys])[0]
-        fkeys, fseg = stream.keys[sel], stream.seg[sel]
-        prev: List[int] = []
-        last: Dict[int, Tuple[int, int]] = {}
-        for fi, (k, sg) in enumerate(zip(fkeys, fseg)):
-            entry = last.get(int(k))
-            prev.append(entry[0] if entry is not None
-                        and entry[1] == sg else -1)
-            last[int(k)] = (fi, int(sg))
-        selections.append((sel, fkeys, fseg))
-        problems.append((prev, stream.size[sel].astype(np.float64)))
-    kstats: Dict = {}
-    dists = stack_distances_batch(problems, stats=kstats)
-    _tally(telemetry, "stack", kstats)
-    for (stream, sig, _), (sel, fkeys, fseg), dist in zip(
-            pending, selections, dists):
-        fsizes = stream.size[sel]
-        # distance from each key's final per-segment reference to its
-        # segment's end: resident at the wipe (or run end) iff
-        # end_dist + size <= capacity, so at capacity C the eviction
-        # count is (admitted misses) − (keys resident at segment ends)
-        end_dist, end_size = [], []
-        tot: Dict[int, int] = {}
-        seen: Set[Tuple[int, int]] = set()
-        for r in range(len(sel) - 1, -1, -1):
-            sk = (int(fseg[r]), int(fkeys[r]))
-            if sk in seen:
+    with TraceAnnotation(spans.DISTANCES):
+        from repro.kernels.stack_distance import stack_distances_batch
+        pending: List[Tuple[_CacheStream, bytes, np.ndarray]] = []
+        seen_sigs: Set[Tuple[int, bytes]] = set()
+        for stream, sig, mask in wanted:
+            if sig in stream.variants or (id(stream), sig) in seen_sigs:
                 continue
-            seen.add(sk)
-            end_dist.append(tot.get(sk[0], 0))
-            end_size.append(int(fsizes[r]))
-            tot[sk[0]] = tot.get(sk[0], 0) + int(fsizes[r])
-        stream.variants[sig] = {
-            "sel": sel, "dist": dist, "sizes": fsizes,
-            "end_dist": np.asarray(end_dist, np.float64),
-            "end_size": np.asarray(end_size, np.int64),
-        }
+            seen_sigs.add((id(stream), sig))
+            pending.append((stream, sig, mask))
+        if not pending:
+            return
+        problems = []
+        selections = []
+        for stream, sig, mask in pending:
+            sel = np.nonzero(mask[stream.keys])[0]
+            fkeys, fseg = stream.keys[sel], stream.seg[sel]
+            prev: List[int] = []
+            last: Dict[int, Tuple[int, int]] = {}
+            for fi, (k, sg) in enumerate(zip(fkeys, fseg)):
+                entry = last.get(int(k))
+                prev.append(entry[0] if entry is not None
+                            and entry[1] == sg else -1)
+                last[int(k)] = (fi, int(sg))
+            selections.append((sel, fkeys, fseg))
+            problems.append((prev, stream.size[sel].astype(np.float64)))
+        kstats: Dict = {}
+        dists = stack_distances_batch(problems, stats=kstats)
+        _tally(telemetry, "stack", kstats)
+        for (stream, sig, _), (sel, fkeys, fseg), dist in zip(
+                pending, selections, dists):
+            fsizes = stream.size[sel]
+            # distance from each key's final per-segment reference to its
+            # segment's end: resident at the wipe (or run end) iff
+            # end_dist + size <= capacity, so at capacity C the eviction
+            # count is (admitted misses) − (keys resident at segment ends)
+            end_dist, end_size = [], []
+            tot: Dict[int, int] = {}
+            seen: Set[Tuple[int, int]] = set()
+            for r in range(len(sel) - 1, -1, -1):
+                sk = (int(fseg[r]), int(fkeys[r]))
+                if sk in seen:
+                    continue
+                seen.add(sk)
+                end_dist.append(tot.get(sk[0], 0))
+                end_size.append(int(fsizes[r]))
+                tot[sk[0]] = tot.get(sk[0], 0) + int(fsizes[r])
+            stream.variants[sig] = {
+                "sel": sel, "dist": dist, "sizes": fsizes,
+                "end_dist": np.asarray(end_dist, np.float64),
+                "end_size": np.asarray(end_size, np.int64),
+            }
 
 
 def _merged_parent_stream(routing: _CellRouting, q: int,
@@ -2486,27 +2495,23 @@ class _CellPlan:
         return report, (flow_specs, flow_bytes)
 
 
-def _plan_cell_vectorized(cspec: ScenarioSpec, routing_fed: FederationSpec,
-                          fed: Federation, state: Dict,
-                          telemetry: Dict) -> Optional[_CellPlan]:
-    """Build (or reuse) the cell's routing product and wrap it in a
-    policy-point plan.  Routing is cached by the cell spec with its
-    *name* cleared and its federation replaced by ``routing_fed`` (the
-    normalized spec the caller already built to pick the shared
-    federation) — the whole cache-policy sweep column shares one
-    entry."""
+def _column_routing(cspec: ScenarioSpec, routing_fed: FederationSpec,
+                    fed: Federation, state: Dict,
+                    telemetry: Dict) -> Optional[_CellRouting]:
+    """Build (or reuse) the cell's routing product.  Routing is cached
+    by the cell spec with its *name* cleared and its federation
+    replaced by ``routing_fed`` (the normalized spec the caller already
+    built to pick the shared federation) — the whole cache-policy sweep
+    column shares one entry."""
     key = dataclasses.replace(cspec, name="", federation=routing_fed)
-    routing = None
     for known, cached in state["cells"]:
         if known == key:
-            routing = cached
-            break
-    if routing is None:
+            return cached
+    with TraceAnnotation(spans.ROUTE):
         routing = _cell_routing(key, fed, state, telemetry)
-        if routing is None:
-            return None
+    if routing is not None:
         state["cells"].append((key, routing))
-    return _CellPlan(cspec, routing)
+    return routing
 
 
 def _fit_wanted(plan: "_CellPlan", wanted: List, l2: bool = False) -> None:
@@ -2566,10 +2571,11 @@ def run_sweep(spec: SweepSpec, batched: bool = True,
     share a device call); and every cell's contention — the all-at-once
     storm counterfactual of its workload — priced by the pow2-bucketed,
     vmapped max-min kernel.  A handful of jitted calls covers the whole
-    sweep (``report.solver``).  Ineligible cells (sim engine,
-    proxy/cvmfs methods, LFU/TTL victim orders) fall back to a serial
-    :func:`run_scenario`, so a mixed sweep still completes with
-    identical semantics.  ``batched=False`` is the all-serial baseline
+    sweep (``report.solver``); each stage writes a host span into a
+    running profiler's trace (:mod:`repro.spans`).  Ineligible cells
+    (sim engine, proxy/cvmfs methods, LFU/TTL victim orders) fall back
+    to a serial :func:`run_scenario`, so a mixed sweep still completes
+    with identical semantics.  ``batched=False`` is the all-serial baseline
     the benchmarks and parity tests compare against.
 
     ``fit=True`` additionally returns *fitted models* alongside the
@@ -2584,6 +2590,15 @@ def run_sweep(spec: SweepSpec, batched: bool = True,
     parity tests compare, and feed :mod:`repro.core.planner`.
     """
     t0 = time.perf_counter()
+    with TraceAnnotation(spans.SWEEP, cells=len(spec)):
+        report = _run_sweep(spec, batched, price_contention, fit)
+    report.wall_seconds = time.perf_counter() - t0
+    return report
+
+
+def _run_sweep(spec: SweepSpec, batched: bool, price_contention: bool,
+               fit) -> SweepReport:
+    """The body of :func:`run_sweep`, inside its span."""
     shared = _SharedFederations()
     telemetry: Dict[str, object] = {}
     entries: List[Tuple[Dict, ScenarioSpec, Optional[_CellPlan],
@@ -2592,21 +2607,24 @@ def run_sweep(spec: SweepSpec, batched: bool = True,
     fifo_problems: List[Tuple] = []
     dist_wanted: List[Tuple[_CacheStream, bytes, np.ndarray]] = []
     batched_cells = serial_cells = 0
-    for params, cspec in spec.cells():
+    for ci, (params, cspec) in enumerate(spec.cells()):
         plan = None
         if batched and _sweep_batchable(cspec):
             routing_fed = _routing_fedspec(cspec.federation)
             fed, state = shared.get(routing_fed)
-            plan = _plan_cell_vectorized(cspec, routing_fed, fed, state,
-                                         telemetry)
+            routing = _column_routing(cspec, routing_fed, fed, state,
+                                      telemetry)
+            if routing is not None:
+                with TraceAnnotation(spans.CLASSIFY, cell=ci):
+                    plan = _CellPlan(cspec, routing)
+                    plan.offset = len(sim_problems)
+                    plan.fifo_offset = len(fifo_problems)
+                    sim_problems.extend(plan.problems)
+                    fifo_problems.extend(plan.fifo_problems)
+                    dist_wanted.extend(plan.dist_wanted)
+                    if fit:
+                        _fit_wanted(plan, dist_wanted)
         if plan is not None:
-            plan.offset = len(sim_problems)
-            plan.fifo_offset = len(fifo_problems)
-            sim_problems.extend(plan.problems)
-            fifo_problems.extend(plan.fifo_problems)
-            dist_wanted.extend(plan.dist_wanted)
-            if fit:
-                _fit_wanted(plan, dist_wanted)
             batched_cells += 1
             entries.append((dict(params), cspec, plan, None))
         else:
@@ -2635,16 +2653,17 @@ def run_sweep(spec: SweepSpec, batched: bool = True,
     l2_sim_problems: List[Tuple] = []
     l2_fifo_problems: List[Tuple] = []
     l2_dist_wanted: List[Tuple[_CacheStream, bytes, np.ndarray]] = []
-    for params, cspec, plan, report in entries:
-        if plan is not None and plan.routing.fill_targets:
-            plan.prepare_l2(sim_results, fifo_results)
-            plan.l2_offset = len(l2_sim_problems)
-            plan.l2_fifo_offset = len(l2_fifo_problems)
-            l2_sim_problems.extend(plan.l2_problems)
-            l2_fifo_problems.extend(plan.l2_fifo_problems)
-            l2_dist_wanted.extend(plan.l2_dist_wanted)
-            if fit:
-                _fit_wanted(plan, l2_dist_wanted, l2=True)
+    with TraceAnnotation(spans.L2):
+        for params, cspec, plan, report in entries:
+            if plan is not None and plan.routing.fill_targets:
+                plan.prepare_l2(sim_results, fifo_results)
+                plan.l2_offset = len(l2_sim_problems)
+                plan.l2_fifo_offset = len(l2_fifo_problems)
+                l2_sim_problems.extend(plan.l2_problems)
+                l2_fifo_problems.extend(plan.l2_fifo_problems)
+                l2_dist_wanted.extend(plan.l2_dist_wanted)
+                if fit:
+                    _fit_wanted(plan, l2_dist_wanted, l2=True)
     if l2_dist_wanted:
         _resolve_distances(l2_dist_wanted, telemetry)
     l2_sim_results: List = []
@@ -2669,37 +2688,41 @@ def run_sweep(spec: SweepSpec, batched: bool = True,
     problem_bytes = []
     problem_cells: List[SweepCell] = []
     fit_cache: Dict[int, Tuple] = {}
-    for params, cspec, plan, report in entries:
-        if plan is not None:
-            report, (flow_specs, flow_bytes) = plan.finalize(
-                sim_results, fifo_results, l2_sim_results,
-                l2_fifo_results)
-            executor = "batched"
-        else:
-            flow_specs = flow_bytes = None
-            executor = "serial"
-        cell = SweepCell(params=params, name=cspec.name,
-                         engine=cspec.engine, executor=executor,
-                         summary=report.summary())
-        if fit and plan is not None:
-            r = plan.routing
-            hists: Dict[str, Dict] = {}
-            mods: Dict[str, object] = {}
-            pairs = [(r.cache_names[ci], r.streams[ci])
-                     for ci, _m, _a in plan._order]
-            pairs += [(r.cache_names[q], stream)
-                      for q, stream, _m, _a in plan._l2_order]
-            for name, stream in pairs:
-                h, mdl = _fit_products(stream, fit, fit_cache)
-                if h is not None:
-                    hists[name] = h
-                    mods[name] = mdl
-            cell.reuse_histogram = hists
-            cell.models = mods
+    for ci, (params, cspec, plan, report) in enumerate(entries):
+        with TraceAnnotation(spans.FINALIZE, cell=ci):
+            if plan is not None:
+                report, (flow_specs, flow_bytes) = plan.finalize(
+                    sim_results, fifo_results, l2_sim_results,
+                    l2_fifo_results)
+                executor = "batched"
+            else:
+                flow_specs = flow_bytes = None
+                executor = "serial"
+            cell = SweepCell(params=params, name=cspec.name,
+                             engine=cspec.engine, executor=executor,
+                             summary=report.summary())
+            if fit and plan is not None:
+                r = plan.routing
+                hists: Dict[str, Dict] = {}
+                mods: Dict[str, object] = {}
+                pairs = [(r.cache_names[c], r.streams[c])
+                         for c, _m, _a in plan._order]
+                pairs += [(r.cache_names[q], stream)
+                          for q, stream, _m, _a in plan._l2_order]
+                for name, stream in pairs:
+                    h, mdl = _fit_products(stream, fit, fit_cache)
+                    if h is not None:
+                        hists[name] = h
+                        mods[name] = mdl
+                cell.reuse_histogram = hists
+                cell.models = mods
         if executor == "batched" and price_contention and flow_specs:
-            problems.append(sparse_flow_problem(flow_specs))
+            with TraceAnnotation(spans.PRICE):
+                problems.append(sparse_flow_problem(flow_specs))
             problem_bytes.append(np.asarray(flow_bytes))
             problem_cells.append(cell)
+            telemetry["priced_flows"] = (telemetry.get("priced_flows", 0)
+                                         + len(flow_specs))
         cells.append(cell)
     solver: Dict[str, object] = {"solve_calls": 0, "priced_cells": 0}
     if fit:
@@ -2711,17 +2734,18 @@ def run_sweep(spec: SweepSpec, batched: bool = True,
         rates = maxmin_rates_batch(problems, stats=stats)
         solver.update(stats)
         solver["priced_cells"] = len(problems)
-        for cell, nbytes, rr in zip(problem_cells, problem_bytes, rates):
-            rr = np.maximum(rr, 1e-9)
-            cell.pricing = {
-                "peak_flows": int(len(rr)),
-                "min_rate": float(rr.min()) if len(rr) else 0.0,
-                "mean_rate": float(rr.mean()) if len(rr) else 0.0,
-                "storm_finish_seconds": float((nbytes / rr).max())
-                if len(rr) else 0.0,
-            }
+        with TraceAnnotation(spans.PRICE):
+            for cell, nbytes, rr in zip(problem_cells, problem_bytes,
+                                        rates):
+                rr = np.maximum(rr, 1e-9)
+                cell.pricing = {
+                    "peak_flows": int(len(rr)),
+                    "min_rate": float(rr.min()) if len(rr) else 0.0,
+                    "mean_rate": float(rr.mean()) if len(rr) else 0.0,
+                    "storm_finish_seconds": float((nbytes / rr).max())
+                    if len(rr) else 0.0,
+                }
     return SweepReport(
         name=spec.name, axes={k: list(v) for k, v in spec.axes.items()},
-        cells=cells, wall_seconds=time.perf_counter() - t0,
-        batched_cells=batched_cells, serial_cells=serial_cells,
+        cells=cells, batched_cells=batched_cells, serial_cells=serial_cells,
         solver=solver)

@@ -28,7 +28,9 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
+from .. import spans
 from .maxmin import _next_pow2, pad_problem, solve_waterfill
 
 # One problem: (link_caps, flow_links, flow_caps) in the same layout as
@@ -36,6 +38,7 @@ from .maxmin import _next_pow2, pad_problem, solve_waterfill
 Problem = Tuple[Sequence[float], Sequence[Sequence[int]], Sequence[float]]
 
 _solve_batch = jax.jit(jax.vmap(solve_waterfill))
+_SPANS = spans.kernel("waterfill")
 
 
 def _bucket_of(problem: Problem) -> Tuple[int, int, int]:
@@ -66,31 +69,38 @@ def maxmin_rates_batch(problems: Sequence[Problem],
     if stats is not None:
         stats.update(solve_calls=0, buckets=[], problems=len(problems),
                      padded_problems=0)
-    out: List[Optional[np.ndarray]] = [None] * len(problems)
-    by_bucket: Dict[Tuple[int, int, int], List[int]] = {}
-    for i, p in enumerate(problems):
-        by_bucket.setdefault(_bucket_of(p), []).append(i)
-    for (Fp, Lp, width), idxs in sorted(by_bucket.items()):
-        B = _next_pow2(len(idxs), floor=1)
-        caps = np.full((B, Lp), np.inf, np.float32)
-        ids = np.full((B, Fp, width), Lp - 1, np.int32)
-        fcaps = np.zeros((B, Fp), np.float32)
-        for bi, i in enumerate(idxs):
-            caps[bi], ids[bi], fcaps[bi] = pad_problem(
-                *problems[i], Fp=Fp, Lp=Lp, width=width)
-        rates = np.asarray(_solve_batch(caps, ids, fcaps))
-        if stats is not None:
-            stats["solve_calls"] += 1
-            stats["buckets"].append((B, Fp, Lp, width))
-            stats["padded_problems"] += B - len(idxs)
-        for bi, i in enumerate(idxs):
-            link_caps_i, flow_links_i, flow_caps_i = problems[i]
-            res = rates[bi, :len(flow_links_i)].astype(np.float64)
-            # Same loopback parity fixup as maxmin_rates_sparse: an
-            # all-dummy row is indistinguishable from padding inside the
-            # solve but is a real flow bound only by its own cap.
-            for fi, ls in enumerate(flow_links_i):
-                if not ls:
-                    res[fi] = flow_caps_i[fi]
-            out[i] = res
-    return [r if r is not None else np.zeros(0) for r in out]
+    call, pack, device, unpack = _SPANS
+    with TraceAnnotation(call, problems=len(problems)):
+        out: List[Optional[np.ndarray]] = [None] * len(problems)
+        by_bucket: Dict[Tuple[int, int, int], List[int]] = {}
+        for i, p in enumerate(problems):
+            by_bucket.setdefault(_bucket_of(p), []).append(i)
+        for (Fp, Lp, width), idxs in sorted(by_bucket.items()):
+            B = _next_pow2(len(idxs), floor=1)
+            bucket = f"{B}x{Fp}x{Lp}x{width}"
+            with TraceAnnotation(pack, bucket=bucket):
+                caps = np.full((B, Lp), np.inf, np.float32)
+                ids = np.full((B, Fp, width), Lp - 1, np.int32)
+                fcaps = np.zeros((B, Fp), np.float32)
+                for bi, i in enumerate(idxs):
+                    caps[bi], ids[bi], fcaps[bi] = pad_problem(
+                        *problems[i], Fp=Fp, Lp=Lp, width=width)
+            with TraceAnnotation(device, bucket=bucket):
+                rates = np.asarray(_solve_batch(caps, ids, fcaps))
+            if stats is not None:
+                stats["solve_calls"] += 1
+                stats["buckets"].append((B, Fp, Lp, width))
+                stats["padded_problems"] += B - len(idxs)
+            with TraceAnnotation(unpack, bucket=bucket):
+                for bi, i in enumerate(idxs):
+                    link_caps_i, flow_links_i, flow_caps_i = problems[i]
+                    res = rates[bi, :len(flow_links_i)].astype(np.float64)
+                    # Same loopback parity fixup as maxmin_rates_sparse:
+                    # an all-dummy row is indistinguishable from padding
+                    # inside the solve but is a real flow bound only by
+                    # its own cap.
+                    for fi, ls in enumerate(flow_links_i):
+                        if not ls:
+                            res[fi] = flow_caps_i[fi]
+                    out[i] = res
+        return [r if r is not None else np.zeros(0) for r in out]

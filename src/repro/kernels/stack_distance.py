@@ -47,7 +47,9 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
+from .. import spans
 from .maxmin import _next_pow2
 
 # Bucket floors: streams shorter than these pad up so a sweep's ragged
@@ -217,6 +219,12 @@ _dist_batch = jax.jit(jax.vmap(_distances))
 _sim_batch = jax.jit(jax.vmap(_simulate))
 _fifo_batch = jax.jit(jax.vmap(_fifo_replay))
 
+# host spans per call and per bucket, under the kinds ``report.solver``
+# tallies them by
+_STACK_SPANS = spans.kernel("stack")
+_FIFO_SPANS = spans.kernel("fifo")
+_SIM_SPANS = spans.kernel("cache_sim")
+
 
 def _note(stats: Optional[Dict], bucket: Tuple[int, ...], pad: int) -> None:
     if stats is not None:
@@ -242,25 +250,31 @@ def stack_distances_batch(problems: Sequence[DistanceProblem],
     array per problem, ``inf`` marking compulsory misses.
     """
     _init_stats(stats, len(problems))
-    out: List[Optional[np.ndarray]] = [None] * len(problems)
-    by_bucket: Dict[int, List[int]] = {}
-    for i, (prev, _) in enumerate(problems):
-        by_bucket.setdefault(_next_pow2(max(len(prev), 1), floor=_FLOOR_N),
-                             []).append(i)
-    with jax.enable_x64():
-        for Np, idxs in sorted(by_bucket.items()):
-            B = _next_pow2(len(idxs), floor=1)
-            prevs = np.full((B, Np), -1, np.int64)
-            sizes = np.zeros((B, Np), np.float64)
-            for bi, i in enumerate(idxs):
-                p, s = problems[i]
-                prevs[bi, :len(p)] = p
-                sizes[bi, :len(s)] = s
-            dists = np.asarray(_dist_batch(prevs, sizes))
-            _note(stats, (B, Np), B - len(idxs))
-            for bi, i in enumerate(idxs):
-                out[i] = dists[bi, :len(problems[i][0])]
-    return [r if r is not None else np.zeros(0) for r in out]
+    call, pack, device, unpack = _STACK_SPANS
+    with TraceAnnotation(call, problems=len(problems)):
+        out: List[Optional[np.ndarray]] = [None] * len(problems)
+        by_bucket: Dict[int, List[int]] = {}
+        for i, (prev, _) in enumerate(problems):
+            by_bucket.setdefault(_next_pow2(max(len(prev), 1),
+                                            floor=_FLOOR_N), []).append(i)
+        with jax.enable_x64():
+            for Np, idxs in sorted(by_bucket.items()):
+                B = _next_pow2(len(idxs), floor=1)
+                bucket = f"{B}x{Np}"
+                with TraceAnnotation(pack, bucket=bucket):
+                    prevs = np.full((B, Np), -1, np.int64)
+                    sizes = np.zeros((B, Np), np.float64)
+                    for bi, i in enumerate(idxs):
+                        p, s = problems[i]
+                        prevs[bi, :len(p)] = p
+                        sizes[bi, :len(s)] = s
+                with TraceAnnotation(device, bucket=bucket):
+                    dists = np.asarray(_dist_batch(prevs, sizes))
+                _note(stats, (B, Np), B - len(idxs))
+                with TraceAnnotation(unpack, bucket=bucket):
+                    for bi, i in enumerate(idxs):
+                        out[i] = dists[bi, :len(problems[i][0])]
+        return [r if r is not None else np.zeros(0) for r in out]
 
 
 def lru_hits(distances: np.ndarray, ref_sizes: np.ndarray,
@@ -287,36 +301,45 @@ def fifo_sim_batch(problems: Sequence[FifoProblem],
     nothing here, unlike the LRU stack model.
     """
     _init_stats(stats, len(problems))
-    out: List[Optional[Tuple[np.ndarray, int, int]]] = [None] * len(problems)
-    by_bucket: Dict[Tuple[int, int], List[int]] = {}
-    for i, (keys, _, _, _, n_keys, _) in enumerate(problems):
-        bucket = (_next_pow2(max(len(keys), 1), floor=_FLOOR_N),
-                  _next_pow2(max(n_keys, 1), floor=_FLOOR_K))
-        by_bucket.setdefault(bucket, []).append(i)
-    with jax.enable_x64():
-        for (Np, Kp), idxs in sorted(by_bucket.items()):
-            B = _next_pow2(len(idxs), floor=1)
-            keys = np.zeros((B, Np), np.int32)
-            sizes = np.zeros((B, Np), np.float64)
-            admit = np.zeros((B, Np), bool)
-            reset = np.zeros((B, Np), bool)
-            kcum0 = np.zeros((B, Kp), np.float64)
-            cap = np.full(B, np.inf, np.float64)
-            for bi, i in enumerate(idxs):
-                k, s, a, r, _, c = problems[i]
-                keys[bi, :len(k)] = k
-                sizes[bi, :len(s)] = s
-                admit[bi, :len(a)] = a
-                reset[bi, :len(r)] = r
-                cap[bi] = c
-            hits, ev, evb = (np.asarray(x) for x in
-                             _fifo_batch(keys, sizes, admit, reset,
-                                         kcum0, cap))
-            _note(stats, (B, Np, Kp), B - len(idxs))
-            for bi, i in enumerate(idxs):
-                n = len(problems[i][0])
-                out[i] = (hits[bi, :n], int(ev[bi]), int(round(evb[bi])))
-    return [r if r is not None else (np.zeros(0, bool), 0, 0) for r in out]
+    call, pack, device, unpack = _FIFO_SPANS
+    with TraceAnnotation(call, problems=len(problems)):
+        out: List[Optional[Tuple[np.ndarray, int, int]]] = \
+            [None] * len(problems)
+        by_bucket: Dict[Tuple[int, int], List[int]] = {}
+        for i, (keys, _, _, _, n_keys, _) in enumerate(problems):
+            bucket = (_next_pow2(max(len(keys), 1), floor=_FLOOR_N),
+                      _next_pow2(max(n_keys, 1), floor=_FLOOR_K))
+            by_bucket.setdefault(bucket, []).append(i)
+        with jax.enable_x64():
+            for (Np, Kp), idxs in sorted(by_bucket.items()):
+                B = _next_pow2(len(idxs), floor=1)
+                bucket = f"{B}x{Np}x{Kp}"
+                with TraceAnnotation(pack, bucket=bucket):
+                    keys = np.zeros((B, Np), np.int32)
+                    sizes = np.zeros((B, Np), np.float64)
+                    admit = np.zeros((B, Np), bool)
+                    reset = np.zeros((B, Np), bool)
+                    kcum0 = np.zeros((B, Kp), np.float64)
+                    cap = np.full(B, np.inf, np.float64)
+                    for bi, i in enumerate(idxs):
+                        k, s, a, r, _, c = problems[i]
+                        keys[bi, :len(k)] = k
+                        sizes[bi, :len(s)] = s
+                        admit[bi, :len(a)] = a
+                        reset[bi, :len(r)] = r
+                        cap[bi] = c
+                with TraceAnnotation(device, bucket=bucket):
+                    hits, ev, evb = (np.asarray(x) for x in
+                                     _fifo_batch(keys, sizes, admit, reset,
+                                                 kcum0, cap))
+                _note(stats, (B, Np, Kp), B - len(idxs))
+                with TraceAnnotation(unpack, bucket=bucket):
+                    for bi, i in enumerate(idxs):
+                        n = len(problems[i][0])
+                        out[i] = (hits[bi, :n], int(ev[bi]),
+                                  int(round(evb[bi])))
+        return [r if r is not None else (np.zeros(0, bool), 0, 0)
+                for r in out]
 
 
 def cache_sim_batch(problems: Sequence[SimProblem],
@@ -332,33 +355,43 @@ def cache_sim_batch(problems: Sequence[SimProblem],
     per problem, byte-exact against a scalar ``CacheServer`` replay.
     """
     _init_stats(stats, len(problems))
-    out: List[Optional[Tuple[np.ndarray, int, int]]] = [None] * len(problems)
-    by_bucket: Dict[Tuple[int, int], List[int]] = {}
-    for i, (keys, _, _, key_sizes, _, _) in enumerate(problems):
-        bucket = (_next_pow2(max(len(keys), 1), floor=_FLOOR_N),
-                  _next_pow2(max(len(key_sizes), 1), floor=_FLOOR_K))
-        by_bucket.setdefault(bucket, []).append(i)
-    with jax.enable_x64():
-        for (Np, Kp), idxs in sorted(by_bucket.items()):
-            B = _next_pow2(len(idxs), floor=1)
-            keys = np.zeros((B, Np), np.int32)
-            admit = np.zeros((B, Np), bool)
-            reset = np.zeros((B, Np), bool)
-            ksz = np.zeros((B, Kp), np.float64)
-            cap = np.zeros(B, np.float64)
-            fifo = np.zeros(B, bool)
-            for bi, i in enumerate(idxs):
-                k, a, r, s, c, f = problems[i]
-                keys[bi, :len(k)] = k
-                admit[bi, :len(a)] = a
-                reset[bi, :len(r)] = r
-                ksz[bi, :len(s)] = s
-                cap[bi] = c
-                fifo[bi] = f
-            hits, ev, evb = (np.asarray(x) for x in
-                             _sim_batch(keys, admit, reset, ksz, cap, fifo))
-            _note(stats, (B, Np, Kp), B - len(idxs))
-            for bi, i in enumerate(idxs):
-                n = len(problems[i][0])
-                out[i] = (hits[bi, :n], int(ev[bi]), int(round(evb[bi])))
-    return [r if r is not None else (np.zeros(0, bool), 0, 0) for r in out]
+    call, pack, device, unpack = _SIM_SPANS
+    with TraceAnnotation(call, problems=len(problems)):
+        out: List[Optional[Tuple[np.ndarray, int, int]]] = \
+            [None] * len(problems)
+        by_bucket: Dict[Tuple[int, int], List[int]] = {}
+        for i, (keys, _, _, key_sizes, _, _) in enumerate(problems):
+            bucket = (_next_pow2(max(len(keys), 1), floor=_FLOOR_N),
+                      _next_pow2(max(len(key_sizes), 1), floor=_FLOOR_K))
+            by_bucket.setdefault(bucket, []).append(i)
+        with jax.enable_x64():
+            for (Np, Kp), idxs in sorted(by_bucket.items()):
+                B = _next_pow2(len(idxs), floor=1)
+                bucket = f"{B}x{Np}x{Kp}"
+                with TraceAnnotation(pack, bucket=bucket):
+                    keys = np.zeros((B, Np), np.int32)
+                    admit = np.zeros((B, Np), bool)
+                    reset = np.zeros((B, Np), bool)
+                    ksz = np.zeros((B, Kp), np.float64)
+                    cap = np.zeros(B, np.float64)
+                    fifo = np.zeros(B, bool)
+                    for bi, i in enumerate(idxs):
+                        k, a, r, s, c, f = problems[i]
+                        keys[bi, :len(k)] = k
+                        admit[bi, :len(a)] = a
+                        reset[bi, :len(r)] = r
+                        ksz[bi, :len(s)] = s
+                        cap[bi] = c
+                        fifo[bi] = f
+                with TraceAnnotation(device, bucket=bucket):
+                    hits, ev, evb = (np.asarray(x) for x in
+                                     _sim_batch(keys, admit, reset, ksz,
+                                                cap, fifo))
+                _note(stats, (B, Np, Kp), B - len(idxs))
+                with TraceAnnotation(unpack, bucket=bucket):
+                    for bi, i in enumerate(idxs):
+                        n = len(problems[i][0])
+                        out[i] = (hits[bi, :n], int(ev[bi]),
+                                  int(round(evb[bi])))
+        return [r if r is not None else (np.zeros(0, bool), 0, 0)
+                for r in out]

@@ -44,3 +44,9 @@ def scanned(xs):
         return carry + rng.standard_normal(), carry
 
     return jax.lax.scan(body, 0.0, xs)
+
+
+@jax.jit
+def annotated(x):
+    with jax.profiler.TraceAnnotation("span"):  # opened at trace time only
+        return x + 1

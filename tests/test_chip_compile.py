@@ -19,7 +19,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import maxmin
 from repro.kernels.batched_maxmin import _solve_batch
-from repro.kernels.stack_distance import _dist_batch, _fifo_batch
+from repro.kernels.stack_distance import (_FLOOR_K, _FLOOR_N, _dist_batch,
+                                          _fifo_batch, _sim_batch)
 
 V5E_HBM_BYTES = 16 * 2**30
 
@@ -85,3 +86,33 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_persistent_cache):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert 0 < used < V5E_HBM_BYTES, (case, mem)
+
+
+# The benchmark reads each kernel's device seconds by its XLA module's
+# name (``chipbench/metrics/dev_s.*.py``), which JAX takes from the
+# function's name: (program, x64, arguments at the smallest bucket,
+# module name prefix).
+N, K = _FLOOR_N, _FLOOR_K
+MODULE_NAMES = {
+    "distances": (_dist_batch, True, [((1, N), i64), ((1, N), f64)],
+                  "jit__distances"),
+    "fifo": (_fifo_batch, True,
+             [((1, N), i32), ((1, N), f64), ((1, N), b1), ((1, N), b1),
+              ((1, K), f64), ((1,), f64)], "jit__fifo_replay"),
+    "cache_sim": (_sim_batch, True,
+                  [((1, N), i32), ((1, N), b1), ((1, N), b1), ((1, K), f64),
+                   ((1,), f64), ((1,), b1)], "jit__simulate"),
+    "waterfill": (_solve_batch, False,
+                  [((1, 16), f32), ((1, 8, 4), i32), ((1, 8), f32)],
+                  "jit_solve_waterfill"),
+}
+
+
+@pytest.mark.parametrize("kernel", sorted(MODULE_NAMES))
+def test_kernel_module_names_are_the_ones_the_benchmark_reads(kernel):
+    fn, x64, args, prefix = MODULE_NAMES[kernel]
+    with jax.enable_x64(x64):
+        lowered = fn.lower(*[jax.ShapeDtypeStruct(shape, dtype)
+                             for shape, dtype in args])
+    name = lowered.compiler_ir("stablehlo").operation.attributes["sym_name"]
+    assert str(name).strip('"').startswith(prefix), name

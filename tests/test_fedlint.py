@@ -54,7 +54,8 @@ def test_jit_purity_fires_on_bad_fixture():
     assert "helper" in msgs                  # one call level deep
     assert "global" in msgs                  # global mutation
     assert "without a seed" in msgs          # unseeded default_rng in scan
-    assert len(vs) >= 6
+    assert "TraceAnnotation" in msgs         # profiler span in @jax.jit
+    assert len(vs) >= 7
 
 
 def test_jit_purity_silent_on_good_fixture():
