@@ -2217,7 +2217,7 @@ class _CellPlan:
       (:func:`~repro.kernels.stack_distance.fifo_sim_batch`), which
       takes per-reference admit bits directly;
     * the residue (LRU whose admission basis flips mid-stream) → the
-      exact slot state machine
+      exact slot-frontier replay, O(N sqrt N) on two-level byte prefixes
       (:func:`~repro.kernels.stack_distance.cache_sim_batch`).
 
     ``finalize`` then folds per-reference hits into the cell's

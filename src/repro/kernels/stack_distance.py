@@ -3,7 +3,7 @@ vectorized single-capacity LRU/FIFO state machine.
 
 The sweep executor (:func:`repro.core.api.run_sweep`) resolves every
 batched cell's hit/miss pattern without ever *running* a cache.  For
-evicting caches that takes one of two kernels, both jitted and bucketed
+evicting caches that takes one of three kernels, all jitted and bucketed
 to power-of-two shapes like :mod:`repro.kernels.batched_maxmin`:
 
 * :func:`stack_distances_batch` — the Mattson / reuse-distance kernel.
@@ -24,19 +24,27 @@ to power-of-two shapes like :mod:`repro.kernels.batched_maxmin`:
   copy admitted *earlier* keeps hitting — the filter applies on miss,
   not on lookup), FIFO victim order (not a stack algorithm), and
   payloads larger than the whole cache.  Each reference carries a
-  precomputed ``admit`` bit; eviction picks resident keys in ascending
-  priority (last-access counter for LRU, admit counter for FIFO) until
-  the insert fits, via an in-step sort + exclusive cumulative sum.
+  precomputed ``admit`` bit; eviction takes resident keys in ascending
+  priority (last access for LRU, admission for FIFO) until the insert
+  fits.  Priority is a slot index written once, at the reference's own
+  step, so eviction only ever takes a *prefix* of slot order: the
+  resident set is every occupied slot at or above a frontier that only
+  moves forward, and finding the last victim is a search on two-level
+  byte prefixes over the slots, O(sqrt N) lanes a step.
 
-Cold restarts appear in both kernels as stream markers: a reset wipes
+* :func:`fifo_sim_batch` — the FIFO-only replay: with no touches the
+  same prefix argument holds over admitted bytes alone, so the frontier
+  is a ``searchsorted`` on the cumulative-admitted curve.
+
+Cold restarts appear in every kernel as stream markers: a reset wipes
 residency without counting evictions (the disk came back empty; nothing
 was *chosen* as a victim), mirroring ``CacheServer.clear``.
 
 Byte counters must be exact — a one-byte error flips an eviction
-decision and breaks the sweep's cell-exact parity guarantee — so both
+decision and breaks the sweep's cell-exact parity guarantee — so the
 kernels run in float64 under a scoped ``with jax.enable_x64():``
 (integers up to 2**53 are exact, far above any capacity the federation
-models).  ``tests/test_stack_distance.py`` holds both
+models).  ``tests/test_stack_distance.py`` holds the
 kernels byte-equal to a scalar :class:`~repro.core.cache.CacheServer`
 oracle replay.
 """
@@ -99,6 +107,13 @@ def _distances(prev: jax.Array, sizes: jax.Array) -> jax.Array:
     return out
 
 
+def _block_width(n: int) -> int:
+    """Slots per block of :func:`_simulate`'s two-level byte prefix: the
+    power of two at or below ``sqrt(n)`` (64 at 4096, 16 at 256), so
+    both levels are about ``sqrt(n)`` lanes wide."""
+    return 1 << ((max(n, 1).bit_length() - 1) // 2)
+
+
 def _simulate(keys: jax.Array, admit: jax.Array, reset: jax.Array,
               key_sizes: jax.Array, capacity: jax.Array,
               fifo: jax.Array):
@@ -106,59 +121,106 @@ def _simulate(keys: jax.Array, admit: jax.Array, reset: jax.Array,
 
     Mirrors :meth:`CacheServer.admit`/``evict_until`` byte for byte:
     a hit touches (LRU) or leaves (FIFO) the key's priority; an
-    admitted miss evicts resident keys in ascending priority while the
-    bytes freed so far are short of ``usage + size - capacity``, then
-    inserts.  Returns ``(hits, evictions, bytes_evicted)``.
+    admitted miss evicts resident keys in ascending priority until
+    ``usage + size <= capacity``, then inserts.  Returns ``(hits,
+    evictions, bytes_evicted)``.
 
     Victim order is kept in *priority slots*: slot ``t`` is written
     only at step ``t``, so slot order IS policy order — an LRU touch
     vacates the key's old slot and occupies slot ``t``, a FIFO hit
-    keeps its admit slot.  Eviction is then a prefix of the occupied
-    slots (exclusive cumulative bytes short of the need), one O(N)
-    cumsum per step instead of a sort or an O(K²) rank comparison —
-    both of which are catastrophic inside a vmapped scan.
+    keeps its admit slot.  Eviction then only ever takes a *prefix* of
+    slot order, so the cache is a moving slot frontier ``F``: slots
+    below ``F`` are gone, and a key is resident iff its latest slot is
+    at or above ``F``.  ``F`` never moves back: an eviction takes every
+    occupied slot from ``F`` up to the first one at which the bytes
+    taken reach the need (each earlier one still leaves the insert
+    short, so ``evict_until`` takes it too), an LRU touch only vacates
+    a resident slot (at or above ``F``), and a cold restart moves ``F``
+    to ``t``.  Nothing behind ``F`` is written or cleared again, so
+    ``base_b``/``base_n`` (bytes and occupied slots below ``F``) stay
+    exact as the FIFO kernel's ``E``/``EN`` do, and the usage is the
+    byte total less ``base_b``.
+
+    Above ``F`` an LRU touch leaves holes, so the byte and occupancy
+    prefixes over slots are kept on two levels that take point updates:
+    ``(n/W, W)`` prefixes within each block and ``(n/W,)`` prefixes of
+    the block totals (:func:`_block_width`), a point update being a
+    suffix add on one row of each.  A victim search counts the blocks
+    whose prefix is short of ``total + size - capacity``, then the
+    slots of the block after them; the new ``F`` is the slot after the
+    last victim, and the bytes and slots evicted are its prefix less
+    ``base``.  Each step touches O(sqrt(n)) lanes and takes no cumsum.
+    A 0-byte slot counts as occupied, so one below the last victim is
+    evicted and counted, as ``evict_until`` does.
     """
-    K = key_sizes.shape[0]
     n = keys.shape[0]
+    W = _block_width(n)
+    nb = -(-n // W)
+    dt = key_sizes.dtype
+    blocks = jnp.arange(nb, dtype=jnp.int32)
+    lanes = jnp.arange(W, dtype=jnp.int32)
+
+    def add(pre_b, pre_n, cum_b, cum_n, slot, db, dn):
+        # slot gains db bytes and dn occupants: every prefix from it on
+        ob, ow = jnp.divmod(slot, W)
+        row = lanes >= ow
+        pre_b = pre_b.at[ob].add(jnp.where(row, db, 0.0))
+        pre_n = pre_n.at[ob].add(jnp.where(row, dn, 0))
+        cum_b = cum_b + jnp.where(blocks >= ob, db, 0.0)
+        cum_n = cum_n + jnp.where(blocks >= ob, dn, 0)
+        return pre_b, pre_n, cum_b, cum_n
 
     def step(carry, x):
-        slot_bytes, slot_key, resident, key_slot, usage, ev, evb = carry
+        (pre_b, pre_n, cum_b, cum_n, key_slot, F, base_b, base_n,
+         ev, evb) = carry
         k, a, r, t = x
-        slot_bytes = jnp.where(r, 0.0, slot_bytes)
-        resident = jnp.where(r, False, resident)
-        usage = jnp.where(r, 0.0, usage)
+        total_b, total_n = cum_b[nb - 1], cum_n[nb - 1]
+        # cold restart: every written slot is gone, uncounted
+        F = jnp.where(r, t, F)
+        base_b = jnp.where(r, total_b, base_b)
+        base_n = jnp.where(r, total_n, base_n)
         s = key_sizes[k]
-        hit = resident[k]
-        do_insert = jnp.logical_and(~hit, a)
-        need = jnp.where(do_insert, usage + s - capacity, 0.0)
-        excl = jnp.cumsum(slot_bytes) - slot_bytes
-        evict_slot = (slot_bytes > 0) & (excl < need)
-        freed = jnp.where(evict_slot, slot_bytes, 0.0).sum()
-        # scatter-max: stale slot_key duplicates carry zero bytes, so
-        # their evict_slot is False and the max is order-independent
-        gone = jnp.zeros(K, bool).at[slot_key].max(evict_slot)
-        resident = resident & ~gone
-        slot_bytes = jnp.where(evict_slot, 0.0, slot_bytes)
-        usage = usage - freed
-        # occupy slot t on admit or LRU touch; vacate the old slot on
-        # touch (an evicted key's old slot is already zero)
-        touch = do_insert | (hit & ~fifo)
         old = key_slot[k]
-        slot_bytes = slot_bytes.at[old].set(
-            jnp.where(hit & touch, 0.0, slot_bytes[old]))
-        slot_bytes = slot_bytes.at[t].set(jnp.where(touch, s, 0.0))
-        slot_key = slot_key.at[t].set(k)
+        hit = old >= F
+        do_insert = jnp.logical_and(~hit, a)
+        # the slot prefix has to reach `target` bytes; the host folds
+        # oversize refusals into `a`
+        target = total_b + s - capacity
+        do_evict = do_insert & (target > base_b)
+        b = jnp.sum(cum_b < target, dtype=jnp.int32)
+        found = b < nb
+        b = jnp.minimum(b, nb - 1)
+        row_b = pre_b[b] + (cum_b[b] - pre_b[b, W - 1])
+        row_n = pre_n[b] + (cum_n[b] - pre_n[b, W - 1])
+        j = jnp.minimum(jnp.sum(row_b < target, dtype=jnp.int32), W - 1)
+        # no slot reaches the target only for an insert larger than
+        # the cache: evict everything
+        new_F = jnp.where(found, b * W + j + 1, t)
+        new_b = jnp.where(found, row_b[j], total_b)
+        new_n = jnp.where(found, row_n[j], total_n)
+        ev = ev + jnp.where(do_evict, new_n - base_n, 0)
+        evb = evb + jnp.where(do_evict, new_b - base_b, 0.0)
+        F = jnp.where(do_evict, new_F, F)
+        base_b = jnp.where(do_evict, new_b, base_b)
+        base_n = jnp.where(do_evict, new_n, base_n)
+        # an LRU hit vacates its old slot; an admit or LRU hit
+        # occupies slot t
+        vac = hit & ~fifo
+        touch = do_insert | vac
+        state = add(pre_b, pre_n, cum_b, cum_n, jnp.maximum(old, 0),
+                    jnp.where(vac, -s, 0.0), -vac.astype(jnp.int32))
+        state = add(*state, t, jnp.where(touch, s, 0.0),
+                    touch.astype(jnp.int32))
         key_slot = key_slot.at[k].set(jnp.where(touch, t, old))
-        resident = resident.at[k].set(hit | do_insert)
-        usage = usage + jnp.where(do_insert, s, 0.0)
-        return (slot_bytes, slot_key, resident, key_slot, usage,
-                ev + evict_slot.sum().astype(jnp.int32), evb + freed), hit
+        return (*state, key_slot, F, base_b, base_n, ev, evb), hit
 
-    carry0 = (jnp.zeros(n, key_sizes.dtype), jnp.zeros(n, jnp.int32),
-              jnp.zeros(K, bool), jnp.zeros(K, jnp.int32),
-              jnp.asarray(0.0, key_sizes.dtype),
-              jnp.asarray(0, jnp.int32), jnp.asarray(0.0, key_sizes.dtype))
-    (_, _, _, _, _, ev, evb), hits = jax.lax.scan(
+    zero_b = jnp.asarray(0.0, dt)
+    zero_n = jnp.asarray(0, jnp.int32)
+    carry0 = (jnp.zeros((nb, W), dt), jnp.zeros((nb, W), jnp.int32),
+              jnp.zeros(nb, dt), jnp.zeros(nb, jnp.int32),
+              jnp.full(key_sizes.shape[0], -1, jnp.int32), zero_n,
+              zero_b, zero_n, zero_n, zero_b)
+    (*_, ev, evb), hits = jax.lax.scan(
         step, carry0, (keys, admit, reset, jnp.arange(n, dtype=jnp.int32)))
     return hits, ev, evb
 

@@ -34,12 +34,15 @@ def _trace(seed, n=300, n_keys=14, max_size=20, reset_rate=0.02):
     return keys, sizes, resets
 
 
-def _oracle(keys, sizes, resets, capacity, policy="lru", fraction=None):
-    """Replay the stream through a real CacheServer."""
+def _oracle(keys, sizes, resets, capacity, policy="lru", fraction=None,
+            admit=None):
+    """Replay the stream through a real CacheServer.  ``admit``, where
+    given, is a per-reference admission decision (a filter that flips
+    mid-stream): a miss whose bit is False is not offered to ``admit``."""
     admission = SizeAwareAdmission(fraction) if fraction is not None else None
     c = _cache(capacity, policy=policy, admission=admission)
     hits = []
-    for k, r in zip(keys, resets):
+    for i, (k, r) in enumerate(zip(keys, resets)):
         if r:
             c.clear()
         path = f"/k{k}"
@@ -47,6 +50,8 @@ def _oracle(keys, sizes, resets, capacity, policy="lru", fraction=None):
             hits.append(True)
             continue
         hits.append(False)
+        if admit is not None and not admit[i]:
+            continue
         c.admit(path, 0, Payload.synthetic(sizes[k], path, 0),
                 object_size=sizes[k])
     return (np.asarray(hits), c.stats.evictions, c.stats.bytes_evicted,
@@ -174,3 +179,99 @@ class TestCacheStateMachine:
                 keys, sizes, resets, capacity,
                 policy="fifo" if fifo else "lru")
             assert (hits == o_hits).all() and (ev, evb) == (o_ev, o_evb)
+
+
+def _replay_both(keys, sizes, resets, capacity, policy, admit=None,
+                 stats=None):
+    """``cache_sim_batch`` against the CacheServer oracle on one stream;
+    returns the oracle's ``(hits, evictions, bytes_evicted)``."""
+    if admit is None:
+        admit = [sizes[k] <= capacity for k in keys]
+    (hits, ev, evb), = cache_sim_batch(
+        [(keys, np.asarray(admit), resets, np.asarray(sizes, float),
+          float(capacity), policy == "fifo")], stats=stats)
+    o_hits, o_ev, o_evb, *_ = _oracle(keys, sizes, resets, capacity,
+                                      policy=policy, admit=admit)
+    assert (hits == o_hits).all()
+    assert (ev, evb) == (o_ev, o_evb)
+    return o_hits, o_ev, o_evb
+
+
+@pytest.mark.parametrize("policy", ["lru", "fifo"])
+class TestSlotFrontier:
+    """The state machine evicts along a moving slot frontier searched on
+    two-level block sums (``W`` slots a block: 16 in the 256 bucket, 64
+    in the 4096 bucket).  These streams put eviction runs, cold
+    restarts and zero-byte keys where that structure has its edges."""
+
+    def test_eviction_runs_span_many_blocks_in_the_4096_bucket(self, policy):
+        rng = random.Random(14)
+        small, capacity = 600, 2000
+        sizes = [rng.randint(1, 3) for _ in range(small)]
+        sizes += [capacity] + [rng.randint(700, 1500) for _ in range(5)]
+        keys = list(range(small))                               # fill
+        keys += [rng.randrange(small) for _ in range(1000)]     # holes
+        keys += [small]                          # needs the whole cache
+        keys += [rng.randrange(small) if rng.random() < 0.97
+                 else small + 1 + rng.randrange(5) for _ in range(1400)]
+        stats = {}
+        _, ev, _ = _replay_both(keys, sizes, [False] * len(keys),
+                                capacity, policy, stats=stats)
+        assert stats["buckets"] == [(1, 4096, 1024)]
+        assert ev > small
+
+    def test_evictions_land_on_block_edges(self, policy):
+        # 48 one-byte keys fill slots 0..47 of a 48-byte cache; a 16-byte
+        # insert then takes block 0 exactly, a 32-byte one blocks 1 and 2
+        unit = 48
+        sizes = [1] * unit + [16, 32, 48]
+        keys = list(range(unit)) + [unit, unit + 1, unit + 2]
+        keys += list(range(unit)) + [unit + 1, unit]
+        resets = [False] * len(keys)
+        _, ev, evb = _replay_both(keys, sizes, resets, unit, policy)
+        assert _replay_both(keys[:unit + 2], sizes, resets, unit,
+                            policy)[1:] == (48, 48)
+        assert ev > 48 and evb > 48
+
+    def test_cold_restart_right_after_an_eviction(self, policy):
+        rng = random.Random(141)
+        sizes = [rng.randint(1, 9) for _ in range(30)] + [40]
+        keys, resets = [], []
+        for _ in range(6):
+            fill = [rng.randrange(30) for _ in range(25)]
+            keys += fill + [30] + fill[:5]
+            resets += [False] * 26 + [True] + [False] * 4
+        hits, ev, _ = _replay_both(keys, sizes, resets, 60, policy)
+        assert ev > 0 and not any(hits[i] for i, r in enumerate(resets)
+                                  if r)
+
+    def test_admission_flip_while_a_copy_is_resident(self, policy):
+        rng = random.Random(142)
+        sizes = [rng.randint(1, 12) for _ in range(40)]
+        keys = [rng.randrange(40) for _ in range(240)]
+        # the filter admits, refuses for a stretch, then admits again:
+        # copies admitted before the flip keep hitting through it
+        admit = [not 80 <= i < 160 for i in range(len(keys))]
+        hits, ev, _ = _replay_both(keys, sizes, [False] * len(keys), 90,
+                                   policy, admit=admit)
+        assert hits[80:160].any() and ev > 0
+
+    def test_insert_that_needs_every_resident_byte(self, policy):
+        # LRU touches leave holes above the frontier; the 60-byte insert
+        # then needs every resident byte of the 60-byte cache
+        sizes = [10, 20, 5, 15, 10, 60, 7]
+        keys = [0, 1, 2, 3, 4, 1, 3, 0, 5, 6, 0, 5]
+        hits, ev, evb = _replay_both(keys, sizes, [False] * len(keys), 60,
+                                     policy)
+        assert ev >= 5 and evb >= 60
+
+    def test_zero_byte_keys_below_and_above_the_last_victim(self, policy):
+        # Z0 A B Z1 C fill 15 bytes; D evicts Z0 (0 bytes, below the
+        # last victim A, counted) and A, and leaves Z1 (above) resident
+        z0, a, b, z1, c, d, e = range(7)
+        sizes = [0, 5, 5, 0, 5, 5, 10]
+        keys = [z0, a, b, z1, c, d, z1, z0, e]
+        hits, ev, evb = _replay_both(keys, sizes, [False] * len(keys), 15,
+                                     policy)
+        assert list(hits) == [False] * 6 + [True, False, False]
+        assert ev >= 2
