@@ -2222,7 +2222,8 @@ class _CellPlan:
       cell with the same filter class: ``hit iff distance + size <=
       capacity``; evictions = admitted misses − keys resident at each
       segment end;
-    * ``fifo`` → the O(N log N) byte-frontier replay
+    * ``fifo`` → the admit-frontier replay, O(N sqrt N) on a two-level
+      cumulative-admitted curve
       (:func:`~repro.kernels.stack_distance.fifo_sim_batch`), which
       takes per-reference admit bits directly;
     * the residue (LRU whose admission basis flips mid-stream) → the

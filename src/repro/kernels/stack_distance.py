@@ -33,8 +33,13 @@ to power-of-two shapes like :mod:`repro.kernels.batched_maxmin`:
   byte prefixes over the slots, O(sqrt N) lanes a step.
 
 * :func:`fifo_sim_batch` — the FIFO-only replay: with no touches the
-  same prefix argument holds over admitted bytes alone, so the frontier
-  is a ``searchsorted`` on the cumulative-admitted curve.
+  same prefix argument holds over the admit sequence alone, so the
+  frontier is found on the cumulative-admitted curve, kept on two
+  levels: a count of the block ends below the target, then of the
+  entries below it in that block's row, O(sqrt N) lanes a step.  The
+  batch axis is explicit, not ``vmap``-ed, so that the step index is
+  one scalar and each step writes the curve in place: batched, the
+  TPU compiler relayouts the whole curve on every step to search it.
 
 Cold restarts appear in every kernel as stream markers: a reset wipes
 residency without counting evictions (the disk came back empty; nothing
@@ -108,9 +113,10 @@ def _distances(prev: jax.Array, sizes: jax.Array) -> jax.Array:
 
 
 def _block_width(n: int) -> int:
-    """Slots per block of :func:`_simulate`'s two-level byte prefix: the
-    power of two at or below ``sqrt(n)`` (64 at 4096, 16 at 256), so
-    both levels are about ``sqrt(n)`` lanes wide."""
+    """Slots per block of the two-level prefixes of :func:`_simulate`
+    and :func:`_fifo_replay`: the power of two at or below ``sqrt(n)``
+    (64 at 4096, 16 at 256), so both levels are about ``sqrt(n)`` lanes
+    wide."""
     return 1 << ((max(n, 1).bit_length() - 1) // 2)
 
 
@@ -228,58 +234,100 @@ def _simulate(keys: jax.Array, admit: jax.Array, reset: jax.Array,
 def _fifo_replay(keys: jax.Array, sizes: jax.Array, admit: jax.Array,
                  reset: jax.Array, kcum0: jax.Array,
                  capacity: jax.Array):
-    """Exact FIFO replay in O(N log N): eviction only ever consumes a
-    *prefix* of the admit sequence (hits never touch, re-admits get new
-    slots), so the whole cache reduces to a moving byte frontier ``E``
-    over the cumulative-admitted-bytes curve.  A key is resident iff
-    the cumulative total at its latest admit exceeds ``E``; evicting
-    for an insert is one ``searchsorted`` — no per-step cumsum, no
-    sort.  Returns ``(hits, evictions, bytes_evicted)``.
+    """Exact FIFO replay of a batch of streams, each at its own
+    capacity: eviction only ever consumes a *prefix* of the admit
+    sequence (hits never touch, re-admits get new slots), so the whole
+    cache reduces to a moving frontier over the admit sequence: ``E``
+    bytes and ``EN`` admits lie behind it.  A key is resident iff its
+    latest admit's ordinal (1, 2, ... over the stream) exceeds ``EN``;
+    an ordinal and not a byte total, because a 0-byte admit shares its
+    byte total with the admit before it and may be on the other side of
+    the frontier.  Returns ``(hits, evictions, bytes_evicted)``, shaped
+    ``(B, N)``, ``(B,)``, ``(B,)``.
 
-    ``kcum0`` is a zeros(K) scratch fixing the per-key state width.
+    The curve (written at every step, flat where nothing is admitted,
+    ``inf`` ahead of the step) is carried on two levels, ``(B, N/W, W)``
+    rows of ``W`` slots (:func:`_block_width`) and the ``(B, N/W)``
+    block ends: the running total at the latest write into each block,
+    ``inf`` where nothing is written.  The frontier search for an
+    evicting insert counts the block ends below its target, then the
+    entries below it in that one row: on a non-decreasing curve that is
+    ``searchsorted(side="left")`` in O(sqrt N) lanes, with no nested
+    search loop.
+
+    The batch axis is explicit rather than ``vmap``-ed so that the step
+    index is one scalar for the whole batch: every per-step write into
+    the curve is a ``dynamic_update_slice`` of a ``(B, 1, 1)`` slice.
+    Under ``vmap`` the index is batched, the write becomes a scatter,
+    and the TPU compiler keeps the carried curve in a layout with the
+    batch axis minor, which it then copies in full on every step to
+    search it.
+
+    ``kcum0`` is a (B, K) array of which only the shape is read: it
+    fixes the width of the per-key ordinals.
     """
-    n = keys.shape[0]
-    big = jnp.inf
+    B, n = keys.shape
+    W = _block_width(n)
+    nb = -(-n // W)
+    dt = sizes.dtype
+    # per-problem picks and one per-problem write: gathers and a scatter
+    # along the batch axis, with no index arrays built every step
+    take = jax.vmap(lambda table, i: table[i])
+    put = jax.vmap(lambda table, i, v: table.at[i].set(v))
+    zero = jnp.asarray(0, jnp.int32)
 
     def step(carry, x):
-        cumB, cumN, kcum, total, totN, E, EN, ev, evb = carry
-        k, s, a, r, t = x
+        cum_b, cum_n, ends, kord, total, tot_n, E, EN, ev, evb, t = carry
+        k, s, a, r = x
         # cold restart: everything already admitted is gone, uncounted
         E = jnp.where(r, total, E)
-        EN = jnp.where(r, totN, EN)
-        hit = kcum[k] > E
-        ins = jnp.logical_and(~hit, a)
+        EN = jnp.where(r, tot_n, EN)
+        ko = take(kord, k)
+        hit = ko > EN
+        ins = ~hit & a
         # evict the minimal admit-prefix putting resident + s under cap
         # (ins implies s <= capacity: the host folds the oversize
-        # refusal into the admit bit)
+        # refusal into the admit bit, so a target beyond every written
+        # slot only arises with no eviction)
         target = total + s - capacity
         do_evict = ins & (target > E)
-        j = jnp.searchsorted(cumB, target)
-        newE = jnp.where(do_evict, cumB[j], E)
-        newN = jnp.where(do_evict, cumN[j], EN)
+        b = jnp.minimum(jnp.sum(ends < target[:, None], axis=1,
+                                dtype=jnp.int32), nb - 1)
+        row_b = take(cum_b, b)
+        j = jnp.minimum(jnp.sum(row_b < target[:, None], axis=1,
+                                dtype=jnp.int32), W - 1)
+        newE = jnp.where(do_evict, take(row_b, j), E)
+        newN = jnp.where(do_evict, take(take(cum_n, b), j), EN)
         ev = ev + (newN - EN)
         evb = evb + (newE - E)
         E, EN = newE, newN
         total = total + jnp.where(ins, s, 0.0)
-        totN = totN + ins.astype(jnp.int32)
-        cumB = cumB.at[t].set(total)     # flat where not inserted
-        cumN = cumN.at[t].set(totN)
-        kcum = kcum.at[k].set(jnp.where(ins, total, kcum[k]))
-        return (cumB, cumN, kcum, total, totN, E, EN, ev, evb), hit
+        tot_n = tot_n + ins.astype(jnp.int32)
+        tb, tw = jnp.divmod(t, W)
+        cum_b = jax.lax.dynamic_update_slice(cum_b, total[:, None, None],
+                                             (zero, tb, tw))
+        cum_n = jax.lax.dynamic_update_slice(cum_n, tot_n[:, None, None],
+                                             (zero, tb, tw))
+        ends = jax.lax.dynamic_update_slice(ends, total[:, None], (zero, tb))
+        kord = put(kord, k, jnp.where(ins, tot_n, ko))
+        return (cum_b, cum_n, ends, kord, total, tot_n, E, EN, ev, evb,
+                t + 1), hit
 
-    zero = jnp.asarray(0.0, sizes.dtype)
-    carry0 = (jnp.full(n, big, sizes.dtype), jnp.zeros(n, jnp.int32),
-              kcum0, zero, jnp.asarray(0, jnp.int32), zero,
-              jnp.asarray(0, jnp.int32), jnp.asarray(0, jnp.int32), zero)
-    (_, _, _, _, _, _, _, ev, evb), hits = jax.lax.scan(
-        step, carry0, (keys, sizes, admit, reset,
-                       jnp.arange(n, dtype=jnp.int32)))
-    return hits, ev, evb
+    zero_b = jnp.zeros(B, dt)
+    zero_n = jnp.zeros(B, jnp.int32)
+    carry0 = (jnp.full((B, nb, W), jnp.inf, dt),
+              jnp.zeros((B, nb, W), jnp.int32),
+              jnp.full((B, nb), jnp.inf, dt),
+              jnp.zeros(kcum0.shape, jnp.int32),
+              zero_b, zero_n, zero_b, zero_n, zero_n, zero_b, zero)
+    (*_, ev, evb, _), hits = jax.lax.scan(
+        step, carry0, (keys.T, sizes.T, admit.T, reset.T))
+    return hits.T, ev, evb
 
 
 _dist_batch = jax.jit(jax.vmap(_distances))
 _sim_batch = jax.jit(jax.vmap(_simulate))
-_fifo_batch = jax.jit(jax.vmap(_fifo_replay))
+_fifo_batch = jax.jit(_fifo_replay)
 
 # host spans per call and per bucket, under the kinds ``report.solver``
 # tallies them by
@@ -356,11 +404,11 @@ def fifo_sim_batch(problems: Sequence[FifoProblem],
                    ) -> List[Tuple[np.ndarray, int, int]]:
     """Replay many FIFO (stream, capacity) problems in few jitted calls.
 
-    Bucketed like :func:`cache_sim_batch`; capacity is vmapped data, so
-    a whole capacity × admission column over one stream shares a device
-    call.  Admission is a per-reference bit (refusals — policy or
-    oversize — simply never insert), so time-varying filters cost
-    nothing here, unlike the LRU stack model.
+    Bucketed like :func:`cache_sim_batch`; capacity is a row of the
+    batch, so a whole capacity × admission column over one stream
+    shares a device call.  Admission is a per-reference bit (refusals —
+    policy or oversize — simply never insert), so time-varying filters
+    cost nothing here, unlike the LRU stack model.
     """
     _init_stats(stats, len(problems))
     call, pack, device, unpack = _FIFO_SPANS

@@ -12,6 +12,9 @@ the planner's jitted solve and the mixture fit are rehearsed by hand.
 """
 from __future__ import annotations
 
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -27,6 +30,12 @@ V5E_HBM_BYTES = 16 * 2**30
 f64, f32, i64, i32 = jnp.float64, jnp.float32, jnp.int64, jnp.int32
 b1 = jnp.bool_
 
+
+def _fifo_args(B, N, K):
+    """``_fifo_batch``'s (shape, dtype) list at bucket (B, N, K)."""
+    return [((B, N), i32), ((B, N), f64), ((B, N), b1), ((B, N), b1),
+            ((B, K), f64), ((B,), f64)]
+
 # (program, x64, argument (shape, dtype) list) at the smoke's buckets:
 # stack distances (B, N); FIFO (B, N, K); sweep pricing (B, F, L, width);
 # the simulator's single waterfill (L, F, width).
@@ -35,10 +44,7 @@ CASES = {
                      [((8, 65536), i64), ((8, 65536), f64)]),
     "dist_4x32768": (_dist_batch, True,
                      [((4, 32768), i64), ((4, 32768), f64)]),
-    "fifo_16x65536x8192": (_fifo_batch, True,
-                           [((16, 65536), i32), ((16, 65536), f64),
-                            ((16, 65536), b1), ((16, 65536), b1),
-                            ((16, 8192), f64), ((16,), f64)]),
+    "fifo_16x65536x8192": (_fifo_batch, True, _fifo_args(16, 65536, 8192)),
     "solve_batch_16x8192x32x8": (_solve_batch, False,
                                  [((16, 32), f32), ((16, 8192, 8), i32),
                                   ((16, 8192), f32)]),
@@ -86,6 +92,29 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_persistent_cache):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert 0 < used < V5E_HBM_BYTES, (case, mem)
+
+
+def test_fifo_step_has_no_nested_loop_and_no_full_copy(one_chip,
+                                                       no_persistent_cache):
+    """At the day cell's bucket the FIFO scan is one ``while``: the
+    frontier search is a count, not a nested binary-search loop, and no
+    step relayouts the carried curve (a copy of B*N/16 elements or more
+    inside the loop would be one)."""
+    B, N, K = 64, 65536, 8192
+    with jax.enable_x64():
+        specs = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                 for shape, dtype in _fifo_args(B, N, K)]
+        hlo = _fifo_batch.lower(*specs).compile().as_text()
+    assert len(re.findall(r"\swhile\(", hlo)) == 1
+    big = []
+    # one computation per block of text that starts at column 0
+    for comp in re.split(r"\n(?=\S)", hlo):
+        if comp.startswith("ENTRY"):
+            continue
+        for dims in re.findall(r"= \w+\[([\d,]*)\]\S* copy\(", comp):
+            if math.prod(int(d) for d in dims.split(",") if d) >= B * N // 16:
+                big.append(dims)
+    assert not big, big
 
 
 # The benchmark reads each kernel's device seconds by its XLA module's
