@@ -2206,19 +2206,25 @@ def _merged_parent_stream(routing: _CellRouting, q: int,
     return m
 
 
-class _CellPlan:
-    """One batched cell, waiting on its hit/miss resolution.
+class _Round:
+    """One resolution round of the batched executor: the hit/miss work
+    of every cell's streams in that round, resolved together in one
+    batched call per kernel, and the answers.
 
-    Construction decides, per cache, how the cell's policy point is
-    evaluated against the shared :class:`_CellRouting` streams:
+    Round 1 holds the caches that clients reach directly.  Round 2 holds
+    the parent-tier (fill-target) caches: their merged direct+fill
+    streams depend on the children's hits, so they exist only once
+    round 1 is resolved.
+
+    :meth:`add` decides how one stream is evaluated at one policy point:
 
     * capacity at or above the stream's whole distinct-key working set
       with nothing refused → nothing can ever evict: hit iff not a
       compulsory miss, no kernel involved;
     * ``lru`` whose admission filter is constant per key (always, bar
       outage meta-location races) → stack distances over the filtered
-      stream (refused keys never enter the stack), computed lazily in
-      one batched kernel call for the whole sweep and shared by every
+      stream (refused keys never enter the stack), computed in one
+      batched kernel call for the whole round and shared by every
       cell with the same filter class: ``hit iff distance + size <=
       capacity``; evictions = admitted misses − keys resident at each
       segment end;
@@ -2229,56 +2235,32 @@ class _CellPlan:
     * the residue (LRU whose admission basis flips mid-stream) → the
       exact slot-frontier replay, O(N sqrt N) on two-level byte prefixes
       (:func:`~repro.kernels.stack_distance.cache_sim_batch`).
-
-    ``finalize`` then folds per-reference hits into the cell's
-    :class:`~repro.core.simclient.ScenarioReport` and pricing flow set.
     """
 
-    def __init__(self, cspec: ScenarioSpec, routing: _CellRouting) -> None:
-        self.spec = cspec
-        self.routing = routing
-        self.offset = 0                  # slot in the global sim problem list
-        self.fifo_offset = 0             # slot in the global fifo list
-        self.problems: List[Tuple] = []      # pending cache_sim problems
-        self.fifo_problems: List[Tuple] = []  # pending fifo_sim problems
-        self.dist_wanted: List[Tuple[_CacheStream, bytes, np.ndarray]] = []
-        self._order: List[Tuple[int, str, object]] = []  # (cache, mode, arg)
-        # round-2 state: parent-tier caches resolve against merged
-        # direct+fill streams that depend on the children's hits, so
-        # their problems are classified in prepare_l2, after round 1
-        self.l2_offset = 0
-        self.l2_fifo_offset = 0
-        self.l2_problems: List[Tuple] = []
-        self.l2_fifo_problems: List[Tuple] = []
-        self.l2_dist_wanted: List[Tuple[_CacheStream, bytes,
-                                        np.ndarray]] = []
-        self._l2_order: List[Tuple[int, _CacheStream, str, object]] = []
-        self._l1_res: Dict[int, Tuple] = {}
-        self.knobs = knobs = _cache_knobs(cspec.federation)
-        for ci in sorted(routing.streams):
-            stream = routing.streams[ci]
-            if not len(stream.req) or ci in routing.fill_targets:
-                continue
-            cap, policy, frac = knobs[routing.cache_names[ci]]
-            mode, arg = self._classify(stream, cap, policy, frac,
-                                       self.problems, self.fifo_problems,
-                                       self.dist_wanted)
-            self._order.append((ci, mode, arg))
+    def __init__(self) -> None:
+        self.dist: List[Tuple[_CacheStream, bytes, np.ndarray]] = []
+        self.fifo: List[Tuple] = []
+        self.sim: List[Tuple] = []
+        self.fifo_results: List[Tuple] = []
+        self.sim_results: List[Tuple] = []
 
-    @staticmethod
-    def _classify(stream: _CacheStream, cap: float, policy: str,
-                  frac: float, problems: List, fifo_problems: List,
-                  dist_wanted: List) -> Tuple[str, object]:
+    def has_work(self) -> bool:
+        return bool(self.dist or self.fifo or self.sim)
+
+    def add(self, stream: _CacheStream, cap: float, policy: str,
+            frac: float) -> Tuple[str, object]:
+        """Queue one stream at one policy point; ``(mode, arg)`` finds
+        its answer in :meth:`answer` once the round is resolved."""
         refused = stream.size > cap
         if frac < 1.0:
             refused = refused | (stream.eff_obj > frac * cap)
         if not refused.any() and cap >= stream.total_key_bytes:
             return "fits", None
         if policy == "fifo":
-            fifo_problems.append(
+            self.fifo.append(
                 (stream.keys, stream.size.astype(np.float64),
                  ~refused, stream.reset, stream.n_keys, float(cap)))
-            return "fifo", len(fifo_problems) - 1
+            return "fifo", len(self.fifo) - 1
         if stream.eff_const:
             # the filter refuses a key always or never → exact as a
             # filtered stack; cells sharing the filter class share
@@ -2286,19 +2268,36 @@ class _CellPlan:
             admitted = np.ones(stream.n_keys, bool)
             admitted[stream.keys[refused]] = False
             sig = admitted.tobytes()
-            dist_wanted.append((stream, sig, admitted))
+            self.dist.append((stream, sig, admitted))
             return "dist", sig
-        problems.append(
+        self.sim.append(
             (stream.keys, ~refused, stream.reset,
              stream.key_sizes.astype(np.float64), float(cap), False))
-        return "sim", len(problems) - 1
+        return "sim", len(self.sim) - 1
 
-    def _resolve(self, stream: _CacheStream, cap: float, frac: float,
-                 mode: str, arg: object, sim_results: Sequence,
-                 fifo_results: Sequence, sim_base: int,
-                 fifo_base: int) -> Tuple:
+    def resolve(self, telemetry: Dict) -> None:
+        """Run the round's kernels: the stack-distance variants, then
+        the FIFO replays, then the state-machine replays.  Each entry is
+        imported from its module at call time, so a wrapper put on the
+        module attribute (a benchmark's span, a test's fault) sees the
+        call."""
+        if self.dist:
+            _resolve_distances(self.dist, telemetry)
+        if self.fifo:
+            from repro.kernels.stack_distance import fifo_sim_batch
+            fifo_stats: Dict = {}
+            self.fifo_results = fifo_sim_batch(self.fifo, stats=fifo_stats)
+            _tally(telemetry, "fifo", fifo_stats)
+        if self.sim:
+            from repro.kernels.stack_distance import cache_sim_batch
+            sim_stats: Dict = {}
+            self.sim_results = cache_sim_batch(self.sim, stats=sim_stats)
+            _tally(telemetry, "cache_sim", sim_stats)
+
+    def answer(self, stream: _CacheStream, cap: float, frac: float,
+               mode: str, arg: object) -> Tuple:
         """(hits, evictions, bytes_evicted, admission_rejects) for one
-        stream at one policy point, from the batched kernel answers."""
+        stream at one policy point, from the resolved round."""
         policy_refused = (stream.eff_obj > frac * cap if frac < 1.0
                           else None)
         if mode == "fits":
@@ -2318,32 +2317,56 @@ class _CellPlan:
             rejects = (int(policy_refused.sum())
                        if policy_refused is not None else 0)
         else:
-            results = fifo_results if mode == "fifo" else sim_results
-            base = fifo_base if mode == "fifo" else sim_base
-            hits, ev, evb = results[base + arg]
+            results = self.fifo_results if mode == "fifo" else self.sim_results
+            hits, ev, evb = results[arg]
             rejects = (int((~hits & policy_refused).sum())
                        if policy_refused is not None else 0)
         return hits, ev, evb, rejects
 
-    def _resolve_l1(self, sim_results: Sequence,
-                    fifo_results: Sequence) -> None:
+
+class _CellPlan:
+    """One batched cell, waiting on its hit/miss resolution.
+
+    Construction queues, in round 1, each cache's stream of the shared
+    :class:`_CellRouting` at the cell's policy point; ``prepare_l2``
+    queues the parent-tier caches' merged streams in round 2 (see
+    :class:`_Round` for how a point is evaluated).  ``finalize`` then
+    folds the rounds' per-reference hits into the cell's
+    :class:`~repro.core.simclient.ScenarioReport` and pricing flow set.
+    """
+
+    def __init__(self, cspec: ScenarioSpec, routing: _CellRouting,
+                 round1: _Round) -> None:
+        self.spec = cspec
+        self.routing = routing
+        # (cache, stream, mode, arg) per queued stream, by round
+        self._order: List[Tuple[int, _CacheStream, str, object]] = []
+        self._l2_order: List[Tuple[int, _CacheStream, str, object]] = []
+        self._l1_res: Dict[int, Tuple] = {}
+        self.knobs = knobs = _cache_knobs(cspec.federation)
+        for ci in sorted(routing.streams):
+            stream = routing.streams[ci]
+            if not len(stream.req) or ci in routing.fill_targets:
+                continue
+            cap, policy, frac = knobs[routing.cache_names[ci]]
+            mode, arg = round1.add(stream, cap, policy, frac)
+            self._order.append((ci, stream, mode, arg))
+
+    def _resolve_l1(self, round1: _Round) -> None:
         if self._l1_res:
             return
         r = self.routing
-        for ci, mode, arg in self._order:
+        for ci, stream, mode, arg in self._order:
             cap, _policy, frac = self.knobs[r.cache_names[ci]]
-            self._l1_res[ci] = self._resolve(
-                r.streams[ci], cap, frac, mode, arg, sim_results,
-                fifo_results, self.offset, self.fifo_offset)
+            self._l1_res[ci] = round1.answer(stream, cap, frac, mode, arg)
 
-    def prepare_l2(self, sim_results: Sequence,
-                   fifo_results: Sequence) -> None:
+    def prepare_l2(self, round1: _Round, round2: _Round) -> None:
         """Resolve the children, derive (or reuse) each fill target's
-        merged stream, and classify its round-2 problem."""
+        merged stream, and queue it in round 2."""
         r = self.routing
         if not r.fill_targets:
             return
-        self._resolve_l1(sim_results, fifo_results)
+        self._resolve_l1(round1)
         hits_by_child = {ci: res[0] for ci, res in self._l1_res.items()}
         for q in sorted(r.fill_targets):
             children = tuple(
@@ -2359,20 +2382,15 @@ class _CellPlan:
             if stream is None:
                 continue
             capq, policyq, fracq = self.knobs[r.cache_names[q]]
-            mode, arg = self._classify(stream, capq, policyq, fracq,
-                                       self.l2_problems,
-                                       self.l2_fifo_problems,
-                                       self.l2_dist_wanted)
+            mode, arg = round2.add(stream, capq, policyq, fracq)
             self._l2_order.append((q, stream, mode, arg))
 
-    def finalize(self, sim_results: List, fifo_results: List,
-                 l2_sim_results: Sequence = (),
-                 l2_fifo_results: Sequence = ()
+    def finalize(self, round1: _Round, round2: _Round
                  ) -> Tuple[ScenarioReport, Tuple]:
         r = self.routing
         knobs = self.knobs
         n = r.n
-        self._resolve_l1(sim_results, fifo_results)
+        self._resolve_l1(round1)
         hit_chunks = np.zeros(n, np.int64)
         miss_chunks = np.zeros(n, np.int64)
         miss_secs = np.zeros(n, np.float64)
@@ -2384,8 +2402,7 @@ class _CellPlan:
         tier_fill = {t: 0 for t in r.all_tiers}
         req_pulled = np.zeros(n, bool)       # request had >= 1 miss
         l2_pulled: Set[Tuple[int, int]] = set()
-        for ci, mode, arg in self._order:
-            stream = r.streams[ci]
+        for ci, stream, _mode, _arg in self._order:
             hits, ev, evb, rejects = self._l1_res[ci]
             evictions += ev
             bytes_evicted += evb
@@ -2411,9 +2428,8 @@ class _CellPlan:
             req_pulled[stream.req[miss]] = True
         for q, stream, mode, arg in self._l2_order:
             capq, _policyq, fracq = knobs[r.cache_names[q]]
-            hits, ev, evb, rejects = self._resolve(
-                stream, capq, fracq, mode, arg, l2_sim_results,
-                l2_fifo_results, self.l2_offset, self.l2_fifo_offset)
+            hits, ev, evb, rejects = round2.answer(stream, capq, fracq,
+                                                   mode, arg)
             evictions += ev
             bytes_evicted += evb
             admission_rejects += rejects
@@ -2524,20 +2540,16 @@ def _column_routing(cspec: ScenarioSpec, routing_fed: FederationSpec,
     return routing
 
 
-def _fit_wanted(plan: "_CellPlan", wanted: List, l2: bool = False) -> None:
-    """Queue the *unfiltered* (all keys admitted) stack-distance
-    variant of every stream the plan touches — the capacity-free reuse
-    profile the differentiable cache models fit.  Rides the same
-    batched kernel call as the cells' own variants; streams that
-    already resolve through an all-admitted ``dist`` variant share it
-    byte for byte."""
-    order = ([(stream, None) for _q, stream, _m, _a in plan._l2_order]
-             if l2 else
-             [(plan.routing.streams[ci], None)
-              for ci, _m, _a in plan._order])
-    for stream, _ in order:
+def _fit_wanted(rnd: _Round, order: Sequence[Tuple]) -> None:
+    """Queue in ``rnd`` the *unfiltered* (all keys admitted)
+    stack-distance variant of every stream in a plan's ``order`` — the
+    capacity-free reuse profile the differentiable cache models fit.
+    Rides the same batched kernel call as the cells' own variants;
+    streams that already resolve through an all-admitted ``dist``
+    variant share it byte for byte."""
+    for _cache, stream, _mode, _arg in order:
         admitted = np.ones(stream.n_keys, bool)
-        wanted.append((stream, admitted.tobytes(), admitted))
+        rnd.dist.append((stream, admitted.tobytes(), admitted))
 
 
 def _fit_products(stream: _CacheStream, fit, cache: Dict[int, Tuple]
@@ -2575,10 +2587,12 @@ def run_sweep(spec: SweepSpec, batched: bool = True,
     ``batched=True`` routes eligible analytic cells through the
     vectorized executor: pristine federations, routing tables and
     per-cache request streams shared across each cache-policy sweep
-    column; hit/miss resolved by the stack-distance kernel (one pass
-    answers every LRU capacity in the column) or the batched LRU/FIFO
-    state machine (capacity × policy × admission points of one stream
-    share a device call); and every cell's contention — the all-at-once
+    column; hit/miss resolved in rounds (:class:`_Round`: the caches
+    clients reach, then the parent-tier caches their misses fill from),
+    each round by the stack-distance kernel (one pass answers every LRU
+    capacity in the column), the FIFO frontier replay or the LRU state
+    machine (capacity × policy × admission points of one stream share a
+    device call); and every cell's contention — the all-at-once
     storm counterfactual of its workload — priced by the pow2-bucketed,
     vmapped max-min kernel.  A handful of jitted calls covers the whole
     sweep (``report.solver``); each stage writes a host span into a
@@ -2613,9 +2627,7 @@ def _run_sweep(spec: SweepSpec, batched: bool, price_contention: bool,
     telemetry: Dict[str, object] = {}
     entries: List[Tuple[Dict, ScenarioSpec, Optional[_CellPlan],
                         Optional[ScenarioReport]]] = []
-    sim_problems: List[Tuple] = []
-    fifo_problems: List[Tuple] = []
-    dist_wanted: List[Tuple[_CacheStream, bytes, np.ndarray]] = []
+    round1, round2 = _Round(), _Round()
     batched_cells = serial_cells = 0
     for ci, (params, cspec) in enumerate(spec.cells()):
         plan = None
@@ -2626,71 +2638,29 @@ def _run_sweep(spec: SweepSpec, batched: bool, price_contention: bool,
                                       telemetry)
             if routing is not None:
                 with TraceAnnotation(spans.CLASSIFY, cell=ci):
-                    plan = _CellPlan(cspec, routing)
-                    plan.offset = len(sim_problems)
-                    plan.fifo_offset = len(fifo_problems)
-                    sim_problems.extend(plan.problems)
-                    fifo_problems.extend(plan.fifo_problems)
-                    dist_wanted.extend(plan.dist_wanted)
+                    plan = _CellPlan(cspec, routing, round1)
                     if fit:
-                        _fit_wanted(plan, dist_wanted)
+                        _fit_wanted(round1, plan._order)
         if plan is not None:
             batched_cells += 1
             entries.append((dict(params), cspec, plan, None))
         else:
             serial_cells += 1
             entries.append((dict(params), cspec, None, run_scenario(cspec)))
-
-    if dist_wanted:
-        _resolve_distances(dist_wanted, telemetry)
-    sim_results: List = []
-    fifo_results: List = []
-    if fifo_problems:
-        from repro.kernels.stack_distance import fifo_sim_batch
-        fifo_stats: Dict = {}
-        fifo_results = fifo_sim_batch(fifo_problems, stats=fifo_stats)
-        _tally(telemetry, "fifo", fifo_stats)
-    if sim_problems:
-        from repro.kernels.stack_distance import cache_sim_batch
-        sim_stats: Dict = {}
-        sim_results = cache_sim_batch(sim_problems, stats=sim_stats)
-        _tally(telemetry, "cache_sim", sim_stats)
+    round1.resolve(telemetry)
 
     # round 2: parent-tier caches see their direct references merged
     # with the fills the children's misses induced, so their problems
     # only exist once round 1 is resolved — same batched kernels, one
     # more pass, still zero serial cells
-    l2_sim_problems: List[Tuple] = []
-    l2_fifo_problems: List[Tuple] = []
-    l2_dist_wanted: List[Tuple[_CacheStream, bytes, np.ndarray]] = []
     with TraceAnnotation(spans.L2):
         for params, cspec, plan, report in entries:
             if plan is not None and plan.routing.fill_targets:
-                plan.prepare_l2(sim_results, fifo_results)
-                plan.l2_offset = len(l2_sim_problems)
-                plan.l2_fifo_offset = len(l2_fifo_problems)
-                l2_sim_problems.extend(plan.l2_problems)
-                l2_fifo_problems.extend(plan.l2_fifo_problems)
-                l2_dist_wanted.extend(plan.l2_dist_wanted)
+                plan.prepare_l2(round1, round2)
                 if fit:
-                    _fit_wanted(plan, l2_dist_wanted, l2=True)
-    if l2_dist_wanted:
-        _resolve_distances(l2_dist_wanted, telemetry)
-    l2_sim_results: List = []
-    l2_fifo_results: List = []
-    if l2_fifo_problems:
-        from repro.kernels.stack_distance import fifo_sim_batch
-        l2_fifo_stats: Dict = {}
-        l2_fifo_results = fifo_sim_batch(l2_fifo_problems,
-                                         stats=l2_fifo_stats)
-        _tally(telemetry, "fifo", l2_fifo_stats)
-    if l2_sim_problems:
-        from repro.kernels.stack_distance import cache_sim_batch
-        l2_sim_stats: Dict = {}
-        l2_sim_results = cache_sim_batch(l2_sim_problems,
-                                         stats=l2_sim_stats)
-        _tally(telemetry, "cache_sim", l2_sim_stats)
-    if l2_sim_problems or l2_fifo_problems or l2_dist_wanted:
+                    _fit_wanted(round2, plan._l2_order)
+    if round2.has_work():
+        round2.resolve(telemetry)
         telemetry["tier_rounds"] = 2
 
     cells: List[SweepCell] = []
@@ -2702,8 +2672,7 @@ def _run_sweep(spec: SweepSpec, batched: bool, price_contention: bool,
         with TraceAnnotation(spans.FINALIZE, cell=ci):
             if plan is not None:
                 report, (flow_specs, flow_bytes) = plan.finalize(
-                    sim_results, fifo_results, l2_sim_results,
-                    l2_fifo_results)
+                    round1, round2)
                 executor = "batched"
             else:
                 flow_specs = flow_bytes = None
@@ -2715,11 +2684,8 @@ def _run_sweep(spec: SweepSpec, batched: bool, price_contention: bool,
                 r = plan.routing
                 hists: Dict[str, Dict] = {}
                 mods: Dict[str, object] = {}
-                pairs = [(r.cache_names[c], r.streams[c])
-                         for c, _m, _a in plan._order]
-                pairs += [(r.cache_names[q], stream)
-                          for q, stream, _m, _a in plan._l2_order]
-                for name, stream in pairs:
+                for c, stream, _m, _a in plan._order + plan._l2_order:
+                    name = r.cache_names[c]
                     h, mdl = _fit_products(stream, fit, fit_cache)
                     if h is not None:
                         hists[name] = h

@@ -5,13 +5,12 @@ contention for *hundreds* of scenarios per solve: every sweep cell
 contributes one (flows, links) max-min problem — its storm-counterfactual
 flow set — and all cells are solved together.  Calling
 ``maxmin_rates_sparse`` per cell would pay one JIT dispatch (and, for
-each new shape, one compile) per scenario; this module instead
-
-* pads each problem to a power-of-two ``(Fp, Lp, width)`` bucket with the
-  same dummy-link layout as :func:`repro.kernels.maxmin.pad_problem`,
-* groups same-bucket problems into a ``(B, ...)`` stack (B itself padded
-  to a power of two with all-dummy problems), and
-* runs one ``jax.jit(jax.vmap(solve_waterfill))`` call per bucket.
+each new shape, one compile) per scenario; this module instead pads each
+problem to a power-of-two ``(Fp, Lp, width)`` bucket with the same
+dummy-link layout as :func:`repro.kernels.maxmin.pad_problem` and runs
+one ``jax.jit(jax.vmap(solve_waterfill))`` call per bucket through
+:func:`repro.kernels.batching.run_buckets` (which pads the batch ``B``
+to a power of two with all-dummy problems).
 
 Because the waterfilling ``while_loop`` body is idempotent once a
 problem's ``active`` mask empties, vmap's run-until-all-done semantics
@@ -28,10 +27,10 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.profiler import TraceAnnotation
 
 from .. import spans
-from .maxmin import _next_pow2, pad_problem, solve_waterfill
+from .batching import _next_pow2, run_buckets
+from .maxmin import pad_problem, solve_waterfill
 
 # One problem: (link_caps, flow_links, flow_caps) in the same layout as
 # maxmin_rates_sparse — per-flow rows of link indices, per-flow caps.
@@ -50,6 +49,30 @@ def _bucket_of(problem: Problem) -> Tuple[int, int, int]:
             width)
 
 
+def _pack(members: Sequence[Problem], B: int,
+          key: Tuple[int, int, int]) -> Tuple[np.ndarray, ...]:
+    Fp, Lp, width = key
+    caps = np.full((B, Lp), np.inf, np.float32)
+    ids = np.full((B, Fp, width), Lp - 1, np.int32)
+    fcaps = np.zeros((B, Fp), np.float32)
+    for bi, problem in enumerate(members):
+        caps[bi], ids[bi], fcaps[bi] = pad_problem(
+            *problem, Fp=Fp, Lp=Lp, width=width)
+    return caps, ids, fcaps
+
+
+def _unpack(rates: np.ndarray, row: int, problem: Problem) -> np.ndarray:
+    _link_caps, flow_links, flow_caps = problem
+    res = rates[row, :len(flow_links)].astype(np.float64)
+    # Same loopback parity fixup as maxmin_rates_sparse: an all-dummy
+    # row is indistinguishable from padding inside the solve but is a
+    # real flow bound only by its own cap.
+    for fi, ls in enumerate(flow_links):
+        if not ls:
+            res[fi] = flow_caps[fi]
+    return res
+
+
 def maxmin_rates_batch(problems: Sequence[Problem],
                        stats: Optional[Dict] = None) -> List[np.ndarray]:
     """Solve many independent max-min problems in few jitted calls.
@@ -66,41 +89,5 @@ def maxmin_rates_batch(problems: Sequence[Problem],
     filler).  The sweep report surfaces these so benches can assert
     "one call priced the whole column".
     """
-    if stats is not None:
-        stats.update(solve_calls=0, buckets=[], problems=len(problems),
-                     padded_problems=0)
-    call, pack, device, unpack = _SPANS
-    with TraceAnnotation(call, problems=len(problems)):
-        out: List[Optional[np.ndarray]] = [None] * len(problems)
-        by_bucket: Dict[Tuple[int, int, int], List[int]] = {}
-        for i, p in enumerate(problems):
-            by_bucket.setdefault(_bucket_of(p), []).append(i)
-        for (Fp, Lp, width), idxs in sorted(by_bucket.items()):
-            B = _next_pow2(len(idxs), floor=1)
-            bucket = f"{B}x{Fp}x{Lp}x{width}"
-            with TraceAnnotation(pack, bucket=bucket):
-                caps = np.full((B, Lp), np.inf, np.float32)
-                ids = np.full((B, Fp, width), Lp - 1, np.int32)
-                fcaps = np.zeros((B, Fp), np.float32)
-                for bi, i in enumerate(idxs):
-                    caps[bi], ids[bi], fcaps[bi] = pad_problem(
-                        *problems[i], Fp=Fp, Lp=Lp, width=width)
-            with TraceAnnotation(device, bucket=bucket):
-                rates = np.asarray(_solve_batch(caps, ids, fcaps))
-            if stats is not None:
-                stats["solve_calls"] += 1
-                stats["buckets"].append((B, Fp, Lp, width))
-                stats["padded_problems"] += B - len(idxs)
-            with TraceAnnotation(unpack, bucket=bucket):
-                for bi, i in enumerate(idxs):
-                    link_caps_i, flow_links_i, flow_caps_i = problems[i]
-                    res = rates[bi, :len(flow_links_i)].astype(np.float64)
-                    # Same loopback parity fixup as maxmin_rates_sparse:
-                    # an all-dummy row is indistinguishable from padding
-                    # inside the solve but is a real flow bound only by
-                    # its own cap.
-                    for fi, ls in enumerate(flow_links_i):
-                        if not ls:
-                            res[fi] = flow_caps_i[fi]
-                    out[i] = res
-        return [r if r is not None else np.zeros(0) for r in out]
+    return run_buckets(_SPANS, problems, _bucket_of, _pack, _solve_batch,
+                       _unpack, stats)

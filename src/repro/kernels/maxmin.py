@@ -34,6 +34,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from .. import spans
+from .batching import _next_pow2
 
 _SPANS = spans.kernel("maxmin")
 
@@ -110,13 +111,6 @@ def solve_waterfill(link_caps: jax.Array, link_ids: jax.Array,
 
 
 _solve = jax.jit(solve_waterfill)
-
-
-def _next_pow2(n: int, floor: int = 8) -> int:
-    p = floor
-    while p < n:
-        p *= 2
-    return p
 
 
 def flow_bucket(num_flows: int) -> int:

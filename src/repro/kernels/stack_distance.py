@@ -3,8 +3,8 @@ vectorized single-capacity LRU/FIFO state machine.
 
 The sweep executor (:func:`repro.core.api.run_sweep`) resolves every
 batched cell's hit/miss pattern without ever *running* a cache.  For
-evicting caches that takes one of three kernels, all jitted and bucketed
-to power-of-two shapes like :mod:`repro.kernels.batched_maxmin`:
+evicting caches that takes one of three kernels, all jitted and run in
+power-of-two buckets by :func:`repro.kernels.batching.run_buckets`:
 
 * :func:`stack_distances_batch` — the Mattson / reuse-distance kernel.
   LRU with byte-granular ``evict_until`` satisfies the *inclusion
@@ -60,10 +60,9 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.profiler import TraceAnnotation
 
 from .. import spans
-from .maxmin import _next_pow2
+from .batching import _next_pow2, run_buckets
 
 # Bucket floors: streams shorter than these pad up so a sweep's ragged
 # stream/key counts land in very few shapes — each (N, K) shape is one
@@ -336,17 +335,19 @@ _FIFO_SPANS = spans.kernel("fifo")
 _SIM_SPANS = spans.kernel("cache_sim")
 
 
-def _note(stats: Optional[Dict], bucket: Tuple[int, ...], pad: int) -> None:
-    if stats is not None:
-        stats["solve_calls"] += 1
-        stats["buckets"].append(bucket)
-        stats["padded_problems"] += pad
+def _padded(n: int, floor: int) -> int:
+    return _next_pow2(max(n, 1), floor=floor)
 
 
-def _init_stats(stats: Optional[Dict], n: int) -> None:
-    if stats is not None:
-        stats.update(solve_calls=0, buckets=[], problems=n,
-                     padded_problems=0)
+def _pack_distances(members: Sequence[DistanceProblem], B: int,
+                    key: Tuple[int, ...]) -> Tuple[np.ndarray, ...]:
+    (Np,) = key
+    prevs = np.full((B, Np), -1, np.int64)
+    sizes = np.zeros((B, Np), np.float64)
+    for bi, (p, s) in enumerate(members):
+        prevs[bi, :len(p)] = p
+        sizes[bi, :len(s)] = s
+    return prevs, sizes
 
 
 def stack_distances_batch(problems: Sequence[DistanceProblem],
@@ -359,32 +360,12 @@ def stack_distances_batch(problems: Sequence[DistanceProblem],
     O(log) shapes for a whole sweep.  Returns one ``(N_i,)`` float64
     array per problem, ``inf`` marking compulsory misses.
     """
-    _init_stats(stats, len(problems))
-    call, pack, device, unpack = _STACK_SPANS
-    with TraceAnnotation(call, problems=len(problems)):
-        out: List[Optional[np.ndarray]] = [None] * len(problems)
-        by_bucket: Dict[int, List[int]] = {}
-        for i, (prev, _) in enumerate(problems):
-            by_bucket.setdefault(_next_pow2(max(len(prev), 1),
-                                            floor=_FLOOR_N), []).append(i)
-        with jax.enable_x64():
-            for Np, idxs in sorted(by_bucket.items()):
-                B = _next_pow2(len(idxs), floor=1)
-                bucket = f"{B}x{Np}"
-                with TraceAnnotation(pack, bucket=bucket):
-                    prevs = np.full((B, Np), -1, np.int64)
-                    sizes = np.zeros((B, Np), np.float64)
-                    for bi, i in enumerate(idxs):
-                        p, s = problems[i]
-                        prevs[bi, :len(p)] = p
-                        sizes[bi, :len(s)] = s
-                with TraceAnnotation(device, bucket=bucket):
-                    dists = np.asarray(_dist_batch(prevs, sizes))
-                _note(stats, (B, Np), B - len(idxs))
-                with TraceAnnotation(unpack, bucket=bucket):
-                    for bi, i in enumerate(idxs):
-                        out[i] = dists[bi, :len(problems[i][0])]
-        return [r if r is not None else np.zeros(0) for r in out]
+    with jax.enable_x64():
+        return run_buckets(
+            _STACK_SPANS, problems,
+            lambda pr: (_padded(len(pr[0]), _FLOOR_N),),
+            _pack_distances, _dist_batch,
+            lambda dists, row, pr: dists[row, :len(pr[0])], stats)
 
 
 def lru_hits(distances: np.ndarray, ref_sizes: np.ndarray,
@@ -399,6 +380,31 @@ FifoProblem = Tuple[Sequence[int], Sequence[float], Sequence[bool],
                     Sequence[bool], int, float]
 
 
+def _unpack_replay(outputs: Tuple[np.ndarray, ...], row: int,
+                   problem: Tuple) -> Tuple[np.ndarray, int, int]:
+    hits, ev, evb = outputs
+    return (hits[row, :len(problem[0])], int(ev[row]),
+            int(round(evb[row])))
+
+
+def _pack_fifo(members: Sequence[FifoProblem], B: int,
+               key: Tuple[int, ...]) -> Tuple[np.ndarray, ...]:
+    Np, Kp = key
+    keys = np.zeros((B, Np), np.int32)
+    sizes = np.zeros((B, Np), np.float64)
+    admit = np.zeros((B, Np), bool)
+    reset = np.zeros((B, Np), bool)
+    kcum0 = np.zeros((B, Kp), np.float64)
+    cap = np.full(B, np.inf, np.float64)
+    for bi, (k, s, a, r, _, c) in enumerate(members):
+        keys[bi, :len(k)] = k
+        sizes[bi, :len(s)] = s
+        admit[bi, :len(a)] = a
+        reset[bi, :len(r)] = r
+        cap[bi] = c
+    return keys, sizes, admit, reset, kcum0, cap
+
+
 def fifo_sim_batch(problems: Sequence[FifoProblem],
                    stats: Optional[Dict] = None
                    ) -> List[Tuple[np.ndarray, int, int]]:
@@ -410,46 +416,31 @@ def fifo_sim_batch(problems: Sequence[FifoProblem],
     policy or oversize — simply never insert), so time-varying filters
     cost nothing here, unlike the LRU stack model.
     """
-    _init_stats(stats, len(problems))
-    call, pack, device, unpack = _FIFO_SPANS
-    with TraceAnnotation(call, problems=len(problems)):
-        out: List[Optional[Tuple[np.ndarray, int, int]]] = \
-            [None] * len(problems)
-        by_bucket: Dict[Tuple[int, int], List[int]] = {}
-        for i, (keys, _, _, _, n_keys, _) in enumerate(problems):
-            bucket = (_next_pow2(max(len(keys), 1), floor=_FLOOR_N),
-                      _next_pow2(max(n_keys, 1), floor=_FLOOR_K))
-            by_bucket.setdefault(bucket, []).append(i)
-        with jax.enable_x64():
-            for (Np, Kp), idxs in sorted(by_bucket.items()):
-                B = _next_pow2(len(idxs), floor=1)
-                bucket = f"{B}x{Np}x{Kp}"
-                with TraceAnnotation(pack, bucket=bucket):
-                    keys = np.zeros((B, Np), np.int32)
-                    sizes = np.zeros((B, Np), np.float64)
-                    admit = np.zeros((B, Np), bool)
-                    reset = np.zeros((B, Np), bool)
-                    kcum0 = np.zeros((B, Kp), np.float64)
-                    cap = np.full(B, np.inf, np.float64)
-                    for bi, i in enumerate(idxs):
-                        k, s, a, r, _, c = problems[i]
-                        keys[bi, :len(k)] = k
-                        sizes[bi, :len(s)] = s
-                        admit[bi, :len(a)] = a
-                        reset[bi, :len(r)] = r
-                        cap[bi] = c
-                with TraceAnnotation(device, bucket=bucket):
-                    hits, ev, evb = (np.asarray(x) for x in
-                                     _fifo_batch(keys, sizes, admit, reset,
-                                                 kcum0, cap))
-                _note(stats, (B, Np, Kp), B - len(idxs))
-                with TraceAnnotation(unpack, bucket=bucket):
-                    for bi, i in enumerate(idxs):
-                        n = len(problems[i][0])
-                        out[i] = (hits[bi, :n], int(ev[bi]),
-                                  int(round(evb[bi])))
-        return [r if r is not None else (np.zeros(0, bool), 0, 0)
-                for r in out]
+    with jax.enable_x64():
+        return run_buckets(
+            _FIFO_SPANS, problems,
+            lambda pr: (_padded(len(pr[0]), _FLOOR_N),
+                        _padded(pr[4], _FLOOR_K)),
+            _pack_fifo, _fifo_batch, _unpack_replay, stats)
+
+
+def _pack_sim(members: Sequence[SimProblem], B: int,
+              key: Tuple[int, ...]) -> Tuple[np.ndarray, ...]:
+    Np, Kp = key
+    keys = np.zeros((B, Np), np.int32)
+    admit = np.zeros((B, Np), bool)
+    reset = np.zeros((B, Np), bool)
+    ksz = np.zeros((B, Kp), np.float64)
+    cap = np.zeros(B, np.float64)
+    fifo = np.zeros(B, bool)
+    for bi, (k, a, r, s, c, f) in enumerate(members):
+        keys[bi, :len(k)] = k
+        admit[bi, :len(a)] = a
+        reset[bi, :len(r)] = r
+        ksz[bi, :len(s)] = s
+        cap[bi] = c
+        fifo[bi] = f
+    return keys, admit, reset, ksz, cap, fifo
 
 
 def cache_sim_batch(problems: Sequence[SimProblem],
@@ -464,44 +455,9 @@ def cache_sim_batch(problems: Sequence[SimProblem],
     a single device call).  Returns ``(hits, evictions, bytes_evicted)``
     per problem, byte-exact against a scalar ``CacheServer`` replay.
     """
-    _init_stats(stats, len(problems))
-    call, pack, device, unpack = _SIM_SPANS
-    with TraceAnnotation(call, problems=len(problems)):
-        out: List[Optional[Tuple[np.ndarray, int, int]]] = \
-            [None] * len(problems)
-        by_bucket: Dict[Tuple[int, int], List[int]] = {}
-        for i, (keys, _, _, key_sizes, _, _) in enumerate(problems):
-            bucket = (_next_pow2(max(len(keys), 1), floor=_FLOOR_N),
-                      _next_pow2(max(len(key_sizes), 1), floor=_FLOOR_K))
-            by_bucket.setdefault(bucket, []).append(i)
-        with jax.enable_x64():
-            for (Np, Kp), idxs in sorted(by_bucket.items()):
-                B = _next_pow2(len(idxs), floor=1)
-                bucket = f"{B}x{Np}x{Kp}"
-                with TraceAnnotation(pack, bucket=bucket):
-                    keys = np.zeros((B, Np), np.int32)
-                    admit = np.zeros((B, Np), bool)
-                    reset = np.zeros((B, Np), bool)
-                    ksz = np.zeros((B, Kp), np.float64)
-                    cap = np.zeros(B, np.float64)
-                    fifo = np.zeros(B, bool)
-                    for bi, i in enumerate(idxs):
-                        k, a, r, s, c, f = problems[i]
-                        keys[bi, :len(k)] = k
-                        admit[bi, :len(a)] = a
-                        reset[bi, :len(r)] = r
-                        ksz[bi, :len(s)] = s
-                        cap[bi] = c
-                        fifo[bi] = f
-                with TraceAnnotation(device, bucket=bucket):
-                    hits, ev, evb = (np.asarray(x) for x in
-                                     _sim_batch(keys, admit, reset, ksz,
-                                                cap, fifo))
-                _note(stats, (B, Np, Kp), B - len(idxs))
-                with TraceAnnotation(unpack, bucket=bucket):
-                    for bi, i in enumerate(idxs):
-                        n = len(problems[i][0])
-                        out[i] = (hits[bi, :n], int(ev[bi]),
-                                  int(round(evb[bi])))
-        return [r if r is not None else (np.zeros(0, bool), 0, 0)
-                for r in out]
+    with jax.enable_x64():
+        return run_buckets(
+            _SIM_SPANS, problems,
+            lambda pr: (_padded(len(pr[0]), _FLOOR_N),
+                        _padded(len(pr[3]), _FLOOR_K)),
+            _pack_sim, _sim_batch, _unpack_replay, stats)

@@ -44,7 +44,8 @@ DISTANCES = "repro.sweep.distances"
 """``_resolve_distances``: ``prev`` chains, the kernel, end residency."""
 
 L2 = "repro.sweep.l2"
-"""The round-2 ``prepare_l2`` pass over every cell."""
+"""Every cell's ``prepare_l2``: the merged parent-tier streams, queued in
+the executor's second ``_Round``."""
 
 FINALIZE = "repro.sweep.finalize"
 """One cell's counters, results, summary and fits; metadata ``cell``."""
@@ -54,10 +55,11 @@ PRICE = "repro.sweep.price"
 
 KERNEL = "repro.kernel.{kind}"
 """One ``*_batch`` call (``kind``: a ``_tally`` kind or ``waterfill``);
-metadata ``problems``.  Per bucket (metadata ``bucket``) it holds
-``.pack`` (filling the padded arrays), ``.device`` (the jitted call
-through its copy back to the host) and ``.unpack`` (slicing out each
-problem's answer).  Kind ``maxmin`` is one ``maxmin_rates_sparse`` solve
+metadata ``problems``.  All four run the bucket loop of
+``repro.kernels.batching.run_buckets``: per bucket (metadata ``bucket``)
+it holds ``.pack`` (filling the padded arrays), ``.device`` (the jitted
+call through its copy back to the host) and ``.unpack`` (slicing out
+each problem's answer).  Kind ``maxmin`` is one ``maxmin_rates_sparse`` solve
 (metadata ``flows``): ``.pack`` pads it to its bucket, ``.unpack`` is the
 loopback fix-up."""
 

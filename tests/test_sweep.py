@@ -8,7 +8,7 @@ import json
 import pytest
 
 from repro.core import (FederationSpec, FetchRequest, ScenarioSpec,
-                        SweepAggregator, SweepSpec, WorkloadSpec,
+                        SweepAggregator, SweepSpec, TierSpec, WorkloadSpec,
                         run_scenario, run_sweep)
 
 PARITY_INTS = ("requests", "completed", "bytes_moved", "cache_hits",
@@ -329,6 +329,47 @@ class TestEvictionParity:
             assert sum(b[0] for b in buckets) \
                 == s[f"{kind}_problems"] + s[f"{kind}_padded"], kind
         assert 0 < s["min_stream_refs"] <= s["max_stream_refs"]
+
+    def test_kernel_telemetry_is_pinned(self):
+        """The exact batches of a sweep that reaches all four batched
+        kernels in both resolution rounds: two pods filling from a hub
+        cache, under outages (a non-head pod serving before the meta is
+        located sends LRU with admission to the state machine), priced.
+        The values were read from a run of the executor before its
+        rounds and its kernels' bucket loops were merged into one
+        ``_Round`` type and one batch runner; they pin the padding
+        policy (``padded_share.sweep``) through any such refactor."""
+        fed = FederationSpec.fleet(num_pods=2, hosts_per_pod=2)
+        pods = [s for s in fed.sites if s.has_cache]
+        hub = dataclasses.replace(pods[0], name="hub", workers=0)
+        fed = dataclasses.replace(fed, sites=(
+            TierSpec(parent="hub", sites=pods).flatten() + [hub]
+            + [s for s in fed.sites if not s.has_cache]))
+        base = dataclasses.replace(base_spec(n_requests=40), federation=fed)
+        sweep = SweepSpec(name="pinned", base=base, axes={
+            "federation.cache_capacity": [5e8, 1e9, 2e9],
+            "federation.eviction_policy": ["lru", "fifo"],
+            "federation.admission_max_fraction": [1.0, 0.25],
+            "outage_rate": [0.5],
+        })
+        s = run_sweep(sweep, batched=True).solver
+        got = {k: s.get(k) for k in (
+            "stack_calls", "stack_problems", "stack_padded",
+            "stack_buckets", "fifo_calls", "fifo_problems", "fifo_padded",
+            "fifo_buckets", "cache_sim_calls", "cache_sim_problems",
+            "cache_sim_padded", "cache_sim_buckets", "solve_calls",
+            "problems", "padded_problems", "buckets", "tier_rounds")}
+        assert got == {
+            "stack_calls": 2, "stack_problems": 8, "stack_padded": 4,
+            "stack_buckets": [(8, 256), (4, 512)],
+            "fifo_calls": 3, "fifo_problems": 18, "fifo_padded": 6,
+            "fifo_buckets": [(8, 512, 256), (8, 512, 512),
+                             (8, 2048, 1024)],
+            "cache_sim_calls": 1, "cache_sim_problems": 6,
+            "cache_sim_padded": 2, "cache_sim_buckets": [(8, 2048, 1024)],
+            "solve_calls": 1, "problems": 12, "padded_problems": 4,
+            "buckets": [(16, 128, 16, 8)], "tier_rounds": 2,
+        }
 
     def test_admission_rejects_are_counted(self, reports):
         batched, _ = reports

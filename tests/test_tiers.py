@@ -238,6 +238,28 @@ class TestTierSweepParity:
         egress = {c.summary["origin_egress_bytes"] for c in batched.cells}
         assert len(egress) > 1  # the L1/L2 split actually matters
 
+    def test_fit_covers_both_rounds(self, reports):
+        """``fit=True`` queues each round's unfiltered variants: every
+        child cache gets a model from round 1 and the parent-tier cache
+        one from round 2, and the exact cells are what ``fit=False``
+        gives."""
+        batched, _ = reports
+        spec = osdf_spec(n_requests=60)
+        sweep = SweepSpec(name="l1xl2", base=spec, axes={
+            "federation.tier1.cache_capacity": [2 * GB, 6 * GB],
+            "federation.tier2.cache_capacity": [4 * GB, 12 * GB, 24 * GB],
+            "federation.eviction_policy": ["lru", "fifo"],
+        })
+        fitted = run_sweep(sweep, batched=True, fit=True)
+        tiers = spec.federation.site_tiers()
+        caches = {f"{site}/cache": t for site, t in tiers.items()}
+        assert set(caches.values()) == {1, 2}
+        assert set(fitted.fitted_models()) == set(caches)
+        for cf, cb in zip(fitted.cells, batched.cells):
+            assert set(cf.models) == set(caches), cf.params
+            assert cf.summary == cb.summary, cf.params
+        assert fitted.solver["tier_rounds"] == 2
+
 
 class TestTierSweepAxes:
     def test_tier_axis_targets_one_tier(self):
